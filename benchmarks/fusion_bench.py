@@ -21,10 +21,9 @@ Two layers, mirroring how the CI gate works (benchmarks/run.py --smoke):
   pow-2 step padding). Acceptance: fused ≥ 3× sequential throughput with
   per-task predictions matching within 1e-5.
 
-``histogram_smoke``/``histogram_tile_sweep`` cover the Pallas histogram
-kernel: the smoke rows pin the swept tile-table picks (deterministic ints)
-plus an interpret-mode parity check; the full sweep re-measures candidates
-and prints the ranking that produced ``kernels/histogram._TILE_TABLE``.
+``histogram_smoke`` covers the Pallas histogram kernel: its rows pin the
+``pick_tiles`` choices (deterministic ints) plus an interpret-mode parity
+check.
 """
 from __future__ import annotations
 
@@ -284,44 +283,4 @@ def histogram_smoke() -> list[Row]:
     err = float(jnp.abs(kern - ref.histogram_ref(bins, g, h, node, n, b)).max())
     rows.append(("histogram.smoke.kernel_parity_ok", float(err < 1e-4),
                  f"interpret-mode kernel vs ref oracle, max err {err:.2e}"))
-    return rows
-
-
-def histogram_tile_sweep() -> list[Row]:
-    """Re-measure tile candidates (interpret-mode wall time — a launch/grid
-    overhead proxy on CPU; re-run on TPU for real MXU numbers) and report the
-    winner per (F, B) shape. Since the §3.8 fusion the sweep drives
-    ``fused_level_split_tpu`` — the kernel training actually launches, whose
-    per-block work adds the split scan and a wider scratch to the histogram
-    accumulate — and its ranking is what ``_TILE_TABLE`` records."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels.histogram import fused_level_split_tpu
-
-    rows: list[Row] = []
-    rng = np.random.default_rng(0)
-    r, n_nodes = 4800, 8
-    for f, b in _HIST_SHAPES:
-        bins = jnp.asarray(rng.integers(0, b, (r, f)), jnp.int32)
-        g = jnp.asarray(rng.normal(size=r), jnp.float32)
-        h = jnp.asarray(rng.random(r), jnp.float32)
-        node = jnp.asarray(rng.integers(0, n_nodes, r), jnp.int32)
-        best, best_cfg = float("inf"), None
-        for bf, br in itertools.product((1, 2, 4, 8, 16), (128, 256, 512, 1024)):
-            if bf > f or 2 * n_nodes * bf * b * 4 > (4 << 20):
-                continue
-            run = lambda: jax.block_until_ready(fused_level_split_tpu(  # noqa: E731
-                bins, g, h, node, n_nodes=n_nodes, n_bins=b,
-                lam=1.0, min_child_weight=1.0,
-                block_rows=br, block_features=bf, interpret=True,
-            ))
-            run()
-            t0 = time.perf_counter()
-            run()
-            dt = time.perf_counter() - t0
-            if dt < best:
-                best, best_cfg = dt, (bf, br)
-        rows.append((f"histogram.sweep.f{f}_b{b}_ms", best * 1e3,
-                     f"best tile block_f={best_cfg[0]} block_rows={best_cfg[1]}"))
     return rows

@@ -53,7 +53,6 @@ BENCHES = {
     "prepared_data": prepared_data_bench.full,
     "eval_plane": eval_bench.full,
     "asha": asha_bench.full,
-    "histogram_sweep": fusion_bench.histogram_tile_sweep,
     "gbdt_kernel": gbdt_kernel_bench.full,
     "lm_steps": lm_bench.arch_step_times,
     "kernels": lm_bench.kernel_parity,
@@ -116,6 +115,9 @@ def main() -> int:
     p.add_argument("--regress-tolerance", type=float, default=0.20,
                    help="allowed relative makespan regression (default 20%%)")
     args = p.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     table = SMOKE_BENCHES if args.smoke else BENCHES
     names = args.only.split(",") if args.only else list(table)
     lines = ["name,value,derived"]

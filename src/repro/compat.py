@@ -1,9 +1,6 @@
-"""Version compatibility shims for the installed jax.
-
-The codebase targets current jax; these helpers keep it running on older
-installs (e.g. 0.4.x containers) where a handful of APIs differ. Keep every
-version-sensitive call site routed through here so the divergence stays in
-one file.
+"""Thin SPMD helpers over the installed JAX (0.9): the ambient-mesh
+context, ``shard_map`` with optional manual axes, and the per-shard launcher
+the row-sharded tabular families run through.
 """
 from __future__ import annotations
 
@@ -11,36 +8,15 @@ import jax
 
 __all__ = ["set_mesh", "shard_map", "sharded_call"]
 
-
-def set_mesh(mesh):
-    """Context manager binding ``mesh`` as the ambient mesh.
-
-    Newer jax exposes ``jax.set_mesh``; on older versions the Mesh object is
-    itself the context manager that installs the thread-local resource env.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+#: context manager binding a mesh as the ambient mesh
+set_mesh = jax.set_mesh
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, axis_names=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax takes ``check_vma`` and ``axis_names`` (manual axes); older
-    jax spells these ``check_rep`` and ``auto`` (the complement set) on
-    ``jax.experimental.shard_map.shard_map``.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {"axis_names": set(axis_names)} if axis_names is not None else {}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+    """``jax.shard_map``; ``axis_names`` (the manual axes) defaults to all."""
+    kw = {"axis_names": set(axis_names)} if axis_names is not None else {}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def sharded_call(f, *, n_shards, axis="shards", mesh=None):

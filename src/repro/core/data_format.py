@@ -481,6 +481,12 @@ class PreparedDataCache:
         with self._lock:
             return self._budget
 
+    def items(self) -> list[tuple[Hashable, object]]:
+        """Snapshot of the ready entries as ``(key, payload)`` pairs."""
+        with self._lock:
+            return [(k, e.value) for k, e in self._entries.items()
+                    if e.ready.is_set() and e.error is None]
+
     def contains(self, key: Hashable) -> bool:
         with self._lock:
             return key in self._entries
@@ -550,10 +556,10 @@ def prepare_key(data: DenseMatrix, fmt: str,
     """The full cache key for one prepared variant. ``placement`` keys
     device residency: None = the process default device (thread pools share
     it); mesh pools pass a per-slice token so each slice holds its own
-    resident copy (on a real pod the builder device_puts onto the slice —
-    on this CPU container slices are degenerate but the keying is the same);
-    a :class:`ShardedPlacement` keys a row-sharded partition whose entry
-    holds per-shard blocks (DESIGN.md §3.9)."""
+    resident copy (the pool builds it with the slice's device as the
+    default, so the copy lands on that chip); a :class:`ShardedPlacement`
+    keys a row-sharded partition whose entry holds per-shard blocks
+    (DESIGN.md §3.9)."""
     return (data.fingerprint(), format_key(fmt, params), placement)
 
 

@@ -11,9 +11,9 @@ complete:
 
 * :class:`MeshSliceExecutorPool` — the TPU-native adaptation: the device mesh
   is partitioned into submesh slices and each slice is one executor; tasks are
-  compiled train-step callables placed onto their slice. On this CPU container
-  slices are degenerate (1 device) but the partitioning/placement logic is the
-  same code that runs on a pod. It shares the thread pool's scheduling
+  compiled train-step callables placed onto their slice; a one-device slice
+  pins its tasks' arrays and programs to its own chip. It shares the thread
+  pool's scheduling
   semantics: WAL de-dup/resume, per-task error capture, dynamic load-balanced
   queues, and ExecutorFailure re-queue onto surviving slices.
 
@@ -32,6 +32,7 @@ prepared-data cache — so results stream back already ranked-able
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import queue as _queue
 import threading
@@ -825,6 +826,20 @@ def make_slices(mesh, n_slices: int, axis: str = "data"):
     return slices
 
 
+def _device_scope(sl):
+    """Default-device scope for work placed on slice ``sl``: a one-device
+    slice (a mesh of one chip) pins its prepared data, training and eval to
+    that chip — arrays the converters and estimators create land there and
+    jitted programs run there. Other handles (shard groups, stand-ins) keep
+    the process default."""
+    devices = getattr(sl, "devices", None)
+    if devices is not None and getattr(devices, "size", 0) == 1:
+        import jax
+
+        return jax.default_device(devices.flat[0])
+    return contextlib.nullcontext()
+
+
 class ShardGroup:
     """One §3.9 scheduling unit spanning ``n_shards`` mesh slices.
 
@@ -860,9 +875,10 @@ class MeshSliceExecutorPool:
     prepared-data cache with a PER-SLICE placement token, so each slice
     prepares a (dataset, format, params) variant once and every later task
     placed on that slice reuses the slice-resident copy — the §3.3 plane's
-    mesh half. (On a real pod the placement token is where a device_put onto
-    the slice keys; on this CPU container slices are degenerate but the
-    keying/reuse logic is identical.)
+    mesh half. A one-device slice runs its tasks under that device as the
+    default (``_device_scope``): the slice's prepared data, training and
+    eval all live on its own chip, so N one-chip slices of a four-chip host
+    hold N separate copies. Slices run their queues one after another.
 
     Fused units (:class:`repro.core.fusion.FusedBatch`) are run as one
     program on their slice: a custom runner is called with the BATCH and must
@@ -1135,7 +1151,8 @@ class MeshSliceExecutorPool:
         """
         solo: dict[int, TrainTask] = {}
         if isinstance(task, FusedBatch):
-            raw = self._run_fused(eid, task, sl, data, validate)
+            with _device_scope(sl):
+                raw = self._run_fused(eid, task, sl, data, validate)
             solo = {task.tasks[i].task_id: task.unfused_task(i)
                     for i in range(len(task.tasks))}
         elif self.wal.is_done(task.task_id):
@@ -1147,7 +1164,8 @@ class MeshSliceExecutorPool:
                       " executor deaths while claimed (poison task)",
                 quarantined=True)]
         else:
-            raw = [self._run_one(eid, task, sl, data, validate)]
+            with _device_scope(sl):
+                raw = [self._run_one(eid, task, sl, data, validate)]
         results = []
         for res in raw:
             if (not res.ok and not res.quarantined

@@ -1,31 +1,48 @@
-"""GBDT split-finding hot path as Pallas TPU kernels.
+"""GBDT split-finding hot path as a Pallas TPU kernel.
 
 The paper's dominant workload is gradient-boosted trees (864 of its 1,211
 search tasks run XGBoost); histogram construction is the per-level hot spot
 of histogram-based GBDT training. On GPU this is a scatter-add into shared
 memory with atomics; TPU has no fast scatter, so we ADAPT the algorithm to
-the MXU: one-hot(node)ᵀ @ (one-hot(bin) ⊙ grad) turns the scatter into two
-dense matmuls per (feature-block, row-block) tile — a systolic-array-native
-reformulation (see DESIGN.md §2, hardware-adaptation notes).
+the MXU: the scatter becomes ``(one-hot(node) ⊙ grad)ᵀ @ one-hot(bin)``, a
+dense matmul per (feature-block, row-block) tile (DESIGN.md §2, §3.8).
 
-Two kernels share that accumulate core:
+Layout (lane-dense, what the chip's compiler accepts):
 
-* :func:`histogram_tpu` — histograms only (the original kernel; the sweep
-  bench and ``ops.histogram`` keep using it).
-* :func:`fused_level_split_tpu` — the training hot path (DESIGN.md §3.8):
-  the same accumulate PLUS the in-kernel cumsum → gain → masked-argmax
-  split scan, so only ``(best_gain, best_feat, best_split)`` per node (and,
-  when the caller is caching parents for histogram subtraction, the level's
-  histograms) leave VMEM. It also implements the subtraction assembly:
-  fed the compacted smaller-child rows and the cached parent histograms, it
-  derives the sibling as ``parent − small`` in VMEM before scanning.
+* feature·bin is flattened onto the LANE axis: lane ``j`` of a feature block
+  is bin ``j mod B`` of feature ``j div B``, and a block is a multiple of
+  128 lanes wide. Nodes sit on sublanes, padded to a multiple of 8;
+* g and h are separate ``(nodes, F·B)`` planes, never a trailing size-2 axis;
+* rows arrive as ``(rows, F)`` bf16 bin ids (exact: ids < 256) plus
+  lane-major ``(1, rows)`` node / g / h vectors. The bin one-hot of a row
+  block is built on the MXU: ``bins @ E`` (E the 0/1 feature→lane
+  expansion) copies each row's bin id onto its feature's lanes, and one
+  compare against the lane's bin id gives the one-hot — no 3-D reshapes.
+  A feature block reads only its own column group of ``bins``
+  (:func:`_bins_group`): all columns where there are at most 128 features
+  (about one 128-deep MXU pass), else the aligned 128-column group holding
+  the block, so E is never much deeper than the block needs;
+* f32 statistics reach the MXU as three bf16 parts (hi + mid + lo = the
+  f32 value), each product with the exact 0/1 one-hot is exact, and the MXU
+  accumulates in f32 — f32-accurate sums with no reliance on the matmul
+  precision default.
 
-Grid layout: ``(feature_blocks, row_blocks)`` with rows minor-most, so the
-per-feature-block accumulator lives in VMEM scratch across the sequential
-row sweep and is flushed once at the final row block. The split scan runs
-in that flush; per-node bests combine across feature blocks with a strict
-``>`` so the FIRST block attaining the max wins — exactly XLA's flattened
-first-argmax tie-breaking.
+One kernel, :func:`fused_level_split_tpu`, is the training hot path: the
+accumulate PLUS the split scan (segmented lane cumsum → gain → masked
+first-max), so only ``(best_gain, best_feat, best_split)`` per node (and,
+when the caller caches parents for histogram subtraction, the level's
+histograms) leave VMEM. Fed the compacted smaller-child rows and the cached
+parent histograms it derives each sibling as ``parent − small`` before the
+scan. :func:`histogram_tpu` is the same kernel returning histograms only.
+
+Grid: ``(feature_blocks, row_blocks)``, rows minor-most, so the accumulators
+live in VMEM scratch across the sequential row sweep and the scan runs once
+per feature block at the last row block; per-node bests combine across
+feature blocks with a strict ``>`` (earlier block wins ties). The scan's
+summation order differs from the XLA oracle's, so a split whose gain ties
+the best within float rounding may be chosen instead of the oracle's —
+the contract (DESIGN.md §3.8) is that the chosen split's gain, evaluated on
+the reference histogram, equals the best reference gain within tolerance.
 
 Oracles: :func:`repro.kernels.ref.histogram_ref` /
 :func:`repro.kernels.ref.level_split_ref`. Dispatch: ``ops.histogram`` /
@@ -34,296 +51,235 @@ Oracles: :func:`repro.kernels.ref.histogram_ref` /
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["histogram_tpu", "fused_level_split_tpu", "pick_tiles"]
+__all__ = ["histogram_tpu", "fused_level_split_tpu", "pick_tiles",
+           "MAX_KERNEL_BINS"]
 
-#: Swept tile defaults, keyed by power-of-two bin count: n_bins →
-#: (block_features, block_rows). Derived from the benchmark sweep over the
-#: smoke workload's (F, B) shapes (benchmarks/fusion_bench.py
-#: ``histogram_tile_sweep``), re-run against ``fused_level_split_tpu`` after
-#: the §3.8 fusion — the fused kernel's flush step (cumsum + gain + argmax
-#: over the whole feature block) shifts the optimum toward deeper row blocks
-#: at wide B=64 shapes and narrower feature blocks at B ≥ 128, where the
-#: per-flush scan work grows with ``block_f · n_bins``. The winners keep the
-#: flattened minor dimension ``block_f · n_bins`` lane-aligned (a multiple
-#: of 128) without blowing the VMEM scratch (2 · n_nodes · block_f · n_bins
-#: · 4 B). Re-run the sweep on real TPU hardware before trusting absolute
-#: numbers; the CPU interpret-mode proxy ranks launch and grid overhead,
-#: not MXU throughput.
-_TILE_TABLE: dict[int, tuple[int, int]] = {
-    32: (16, 512),
-    64: (16, 1024),
-    128: (2, 1024),
-    256: (4, 256),
-}
+#: bin ids travel as bf16, exact for integers up to 256
+MAX_KERNEL_BINS = 256
+
+_LANES = 128
+_SUBLANES = 8
+#: rows per grid step; on the chip the lane-major node/g/h blocks round it
+#: up to a multiple of 128
+_ROW_TILE = 256
+#: what :func:`pick_tiles` lets one grid step's buffers take (estimated by
+#: :func:`_vmem_bytes`), and the scoped-VMEM limit handed to the compiler
+#: (v5e has 128 MiB of VMEM per core; the default scoped limit is 16 MiB)
+_VMEM_BUDGET = 24 << 20
+_VMEM_LIMIT = 64 << 20
 
 
-#: VMEM scratch budget for the two f32 accumulators (the core has ~16 MB
-#: total; leave room for the input blocks and double-buffering)
-_VMEM_SCRATCH_BUDGET = 4 << 20
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _feature_step(n_bins: int) -> int:
+    """Smallest feature count whose ``features · n_bins`` is lane-aligned."""
+    return _LANES // math.gcd(n_bins, _LANES)
+
+
+def _bins_group(n_features: int, block_f: int) -> int:
+    """Width of the column group of ``bins`` that one feature block reads:
+    every column where one block holds all features or there are at most
+    128 (about one 128-deep MXU pass); otherwise (``block_f`` then a power
+    of two below 128 or a multiple of 128, see :func:`pick_tiles`) the
+    aligned group of ``max(block_f, 128)`` columns that holds the block."""
+    fp = _round_up(n_features, block_f)
+    if block_f == fp or n_features <= _LANES:
+        return fp
+    return max(block_f, _LANES)
+
+
+def _vmem_bytes(block_f: int, n_bins: int, n_nodes: int, block_rows: int,
+                group: int) -> int:
+    """Upper estimate of one grid step's VMEM (worst case: subtraction mode
+    with the histogram output on), counted from the buffer shapes; ``group``
+    is the :func:`_bins_group` width."""
+    w = block_f * n_bins
+    n_pad = _round_up(max(1, n_nodes // 2), _SUBLANES)   # accumulated rows
+    rb = _round_up(block_rows, _LANES)
+    return (
+        2 * rb * _round_up(group, _LANES) * 2     # bins blocks, double-buffered
+        + _round_up(group, 16) * w * 2            # feature→lane expansion E
+        + rb * w * 10                             # replicated ids + one-hot
+        + 6 * n_pad * rb * 6                      # split stats, f32 + bf16
+        + 6 * n_pad * w * 4                       # matmul result
+        + 2 * n_pad * w * 4                       # g/h accumulators
+        + 2 * 2 * 2 * 2 * n_pad * w * 4           # histogram out, 2 sides
+        + 2 * 2 * n_pad * w * 4                   # cached parent in
+        + 16 * 2 * n_pad * w * 4                  # split-scan temporaries
+    )
 
 
 def pick_tiles(n_features: int, n_bins: int, n_rows: int,
                n_nodes: int = 1) -> tuple[int, int]:
-    """(block_features, block_rows) for a histogram shape, from the swept
-    lookup table (nearest power-of-two bin count), clamped to the array AND
-    to the VMEM scratch budget: the accumulators take
-    ``2 · n_nodes · block_f · n_bins · 4`` bytes, so deep-tree levels
-    (large ``n_nodes``) halve ``block_f`` until they fit.
+    """(block_features, block_rows) for a level shape.
 
-    ``block_rows`` never exceeds ``n_rows``: the old
-    ``min(block_r, max(8, n_rows))`` clamp returned 8 for a sub-8-row array
-    — every tiny histogram (profiler samples, unit-test fixtures) was
-    silently padded up to twice over before the kernel's own block padding
-    even ran."""
-    key = min(_TILE_TABLE, key=lambda b: abs(b - n_bins))
-    block_f, block_r = _TILE_TABLE[key]
-    block_f = min(block_f, n_features)
-    while block_f > 1 and 2 * n_nodes * block_f * n_bins * 4 > _VMEM_SCRATCH_BUDGET:
-        block_f //= 2
-    return block_f, max(1, min(block_r, n_rows))
+    ``block_features · n_bins`` is always a multiple of 128 lanes; the block
+    is the widest that fits :data:`_VMEM_BUDGET` (:func:`_vmem_bytes`), so
+    deep levels (large ``n_nodes``) and wide bins take narrower blocks.
+    Past 128 features a split block is a multiple of 128 or a power of two
+    below it, so it lies inside one aligned bins column group
+    (:func:`_bins_group`). ``block_rows`` is :data:`_ROW_TILE` clamped to
+    ``n_rows`` — the honest tile; the chip path pads it up to lane
+    alignment."""
+    step = _feature_step(n_bins)
+    block_r = max(1, min(_ROW_TILE, n_rows))
+    whole = _round_up(n_features, step)
+    if whole <= _LANES:
+        sizes = list(range(whole, 0, -step))
+    else:
+        sizes = [whole] + list(range((whole - 1) // _LANES * _LANES, 0,
+                                     -_LANES))
+        sizes += [1 << k for k in range(6, -1, -1) if (1 << k) >= step]
+    for block_f in sizes:
+        if _vmem_bytes(block_f, n_bins, n_nodes, block_r, _bins_group(
+                n_features, block_f)) <= _VMEM_BUDGET:
+            break
+    return block_f, block_r
 
 
-def _hist_kernel(
-    bins_ref, node_ref, gh_ref, out_ref, acc_g, acc_h,
-    *, n_nodes: int, n_bins: int, block_f: int, n_rblocks: int,
+def _segment_cumsum(x, lane_bin, n_bins: int):
+    """Inclusive prefix sum along lanes within each feature's ``n_bins``
+    segment (Hillis–Steele: log2(B) lane rotations)."""
+    s = 1
+    while s < n_bins:
+        x = x + jnp.where(lane_bin >= s, pltpu.roll(x, s, 1), 0.0)
+        s *= 2
+    return x
+
+
+def _level_kernel(
+    bins_ref, node_ref, g_ref, h_ref, lanes_ref, fmask_ref, sil_ref,
+    parent_ref, scal_ref, *rest,
+    n_pad: int, n_bins: int, n_rblocks: int, per_group: int, subtract: bool,
+    return_hist: bool,
 ):
-    ri = pl.program_id(1)
-
-    @pl.when(ri == 0)
-    def _init():
-        acc_g[...] = jnp.zeros_like(acc_g)
-        acc_h[...] = jnp.zeros_like(acc_h)
-
-    bins = bins_ref[...]                      # (rb, fb) int32
-    node = node_ref[...]                      # (rb, 1) int32
-    gh = gh_ref[...].astype(jnp.float32)      # (rb, 2)
-    rb = bins.shape[0]
-
-    # one-hot(node): (rb, N) — VPU compare against an iota, no gather.
-    node_iota = jax.lax.broadcasted_iota(jnp.int32, (rb, n_nodes), 1)
-    node_oh = (node_iota == node).astype(jnp.float32)
-
-    # one-hot(bin) ⊙ g / ⊙ h: (rb, fb*B)
-    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (rb, block_f, n_bins), 2)
-    bin_oh = (bin_iota == bins[:, :, None]).astype(jnp.float32)
-    gmat = (bin_oh * gh[:, None, None, 0]).reshape(rb, block_f * n_bins)
-    hmat = (bin_oh * gh[:, None, None, 1]).reshape(rb, block_f * n_bins)
-
-    # MXU contractions: (N, rb) @ (rb, fb*B)
-    dn = (((0,), (0,)), ((), ()))
-    acc_g[...] += jax.lax.dot_general(node_oh, gmat, dn, preferred_element_type=jnp.float32)
-    acc_h[...] += jax.lax.dot_general(node_oh, hmat, dn, preferred_element_type=jnp.float32)
-
-    @pl.when(ri == n_rblocks - 1)
-    def _flush():
-        g = acc_g[...].reshape(n_nodes, block_f, n_bins)
-        h = acc_h[...].reshape(n_nodes, block_f, n_bins)
-        out_ref[...] = jnp.stack([g, h], axis=-1).astype(out_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_nodes", "n_bins", "block_rows", "block_features", "interpret"),
-)
-def histogram_tpu(
-    bins: jax.Array,
-    grad: jax.Array,
-    hess: jax.Array,
-    node: jax.Array,
-    *,
-    n_nodes: int,
-    n_bins: int,
-    block_rows: int | None = None,
-    block_features: int | None = None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Per-(node, feature, bin) grad/hess sums; see ``histogram_ref``.
-
-    bins: (R, F) int32 in [0, n_bins); grad/hess: (R,) f32; node: (R,) int32
-    in [0, n_nodes). R and F are padded here to block multiples (pad rows get
-    node = n_nodes, whose one-hot row is all-zero, so they contribute nothing).
-    Tile sizes default to the swept ``_TILE_TABLE`` via :func:`pick_tiles`;
-    pass them explicitly to override (the sweep bench does).
-    """
-    r, f = bins.shape
-    picked_f, picked_r = pick_tiles(f, n_bins, r, n_nodes)
-    block_rows = picked_r if block_rows is None else max(1, min(block_rows, r))
-    if not interpret and block_rows < 8:
-        # real-TPU Mosaic wants >= 8 sublanes in an f32 block; a sub-8-row
-        # histogram pads up through the kernel's own row padding (pad rows
-        # carry node = n_nodes, whose one-hot row is all-zero). Interpret /
-        # CPU keeps the honest unpadded tile pick_tiles reports.
-        block_rows = 8
-    block_features = picked_f if block_features is None else min(block_features, f)
-    pad_r = (-r) % block_rows
-    pad_f = (-f) % block_features
-    bins_p = jnp.pad(bins, ((0, pad_r), (0, pad_f)))
-    node_p = jnp.pad(node.astype(jnp.int32), (0, pad_r), constant_values=n_nodes)
-    gh = jnp.pad(
-        jnp.stack([grad, hess], axis=-1).astype(jnp.float32), ((0, pad_r), (0, 0))
-    )
-    rp, fp = bins_p.shape
-    grid = (fp // block_features, rp // block_rows)
-    out = pl.pallas_call(
-        functools.partial(
-            _hist_kernel,
-            n_nodes=n_nodes,
-            n_bins=n_bins,
-            block_f=block_features,
-            n_rblocks=grid[1],
-        ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, block_features), lambda fi, ri: (ri, fi)),
-            pl.BlockSpec((block_rows, 1), lambda fi, ri: (ri, 0)),
-            pl.BlockSpec((block_rows, 2), lambda fi, ri: (ri, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (n_nodes, block_features, n_bins, 2), lambda fi, ri: (0, fi, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((n_nodes, fp, n_bins, 2), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((n_nodes, block_features * n_bins), jnp.float32),
-            pltpu.VMEM((n_nodes, block_features * n_bins), jnp.float32),
-        ],
-        interpret=interpret,
-    )(bins_p, node_p[:, None], gh)
-    return out[:, :f]
-
-
-# --------------------------------------------------------------------------
-# Fused level kernel: histogram accumulate + split scan (DESIGN.md §3.8).
-# --------------------------------------------------------------------------
-
-def _level_body(
-    bins_ref, node_ref, gh_ref, sil_ref, parent_ref, fmask_ref,
-    lam_ref, mcw_ref, blim_ref, hist_ref, bg_ref, bf_ref, bs_ref,
-    acc_g, acc_h, tot,
-    *, n_acc: int, n_nodes: int, n_bins: int, block_f: int, n_rblocks: int,
-    subtract: bool,
-):
-    """Shared kernel body; ``hist_ref`` is None when the caller skips the
-    histogram output (the final tree level: nothing caches it)."""
+    if return_hist:
+        hist_ref, bg_ref, bf_ref, bs_ref, e_ref, acc_g, acc_h, tot_g, tot_h = rest
+    else:
+        hist_ref = None
+        bg_ref, bf_ref, bs_ref, e_ref, acc_g, acc_h, tot_g, tot_h = rest
     fi = pl.program_id(0)
     ri = pl.program_id(1)
+    f32, bf16 = jnp.float32, jnp.bfloat16
 
     @pl.when(ri == 0)
     def _init():
         acc_g[...] = jnp.zeros_like(acc_g)
         acc_h[...] = jnp.zeros_like(acc_h)
+        # E[k, j] = 1 where lane j belongs to feature k of this block's
+        # bins column group (per_group feature blocks share a group)
+        gw, w = e_ref.shape
+        row_feat = (jax.lax.broadcasted_iota(jnp.int32, (gw, w), 0)
+                    + (fi // per_group) * gw)
+        e_ref[...] = (row_feat.astype(f32) == lanes_ref[0:1, :]).astype(bf16)
 
-    bins = bins_ref[...]                      # (rb, fb) int32
-    node = node_ref[...]                      # (rb, 1) int32; n_acc = dropped
-    gh = gh_ref[...].astype(jnp.float32)      # (rb, 2)
-    rb = bins.shape[0]
+    # one-hot(bin) (rb, W): each row's bin id copied onto its feature's
+    # lanes (exact: one 0/1 term per output), compared with the lane's bin
+    rep = jnp.dot(bins_ref[...], e_ref[...], preferred_element_type=f32)
+    onehot = (rep == lanes_ref[1:2, :]).astype(bf16)
 
-    # one-hot(node): (rb, n_acc) — VPU compare against an iota, no gather;
-    # the pad/dump value n_acc yields an all-zero row, contributing nothing
-    node_iota = jax.lax.broadcasted_iota(jnp.int32, (rb, n_acc), 1)
-    node_oh = (node_iota == node).astype(jnp.float32)
-    bin_iota = jax.lax.broadcasted_iota(jnp.int32, (rb, block_f, n_bins), 2)
-    bin_oh = (bin_iota == bins[:, :, None]).astype(jnp.float32)
-    gmat = (bin_oh * gh[:, None, None, 0]).reshape(rb, block_f * n_bins)
-    hmat = (bin_oh * gh[:, None, None, 1]).reshape(rb, block_f * n_bins)
-    dn = (((0,), (0,)), ((), ()))
-    acc_g[...] += jax.lax.dot_general(node_oh, gmat, dn, preferred_element_type=jnp.float32)
-    acc_h[...] += jax.lax.dot_general(node_oh, hmat, dn, preferred_element_type=jnp.float32)
+    # (one-hot(node) ⊙ stat)ᵀ as hi/mid/lo bf16 parts, g then h: (6·N, rb)
+    rb = node_ref.shape[1]
+    hit = jax.lax.broadcasted_iota(jnp.int32, (n_pad, rb), 0) == node_ref[...]
+    parts = []
+    for stat_ref in (g_ref, h_ref):
+        a = jnp.where(hit, stat_ref[...], 0.0)
+        for _ in range(3):
+            p = a.astype(bf16).astype(f32)
+            parts.append(p)
+            a = a - p
+    lhs = jnp.concatenate(parts, axis=0).astype(bf16)
+    res = jnp.dot(lhs, onehot, preferred_element_type=f32)   # (6·N, W)
+    n = n_pad
+    acc_g[...] += (res[0:n] + res[n:2 * n]) + res[2 * n:3 * n]
+    acc_h[...] += (res[3 * n:4 * n] + res[4 * n:5 * n]) + res[5 * n:6 * n]
 
     @pl.when(ri == n_rblocks - 1)
     def _flush():
-        g_acc = acc_g[...].reshape(n_acc, block_f, n_bins)
-        h_acc = acc_h[...].reshape(n_acc, block_f, n_bins)
-        hist = jnp.stack([g_acc, h_acc], axis=-1)        # (n_acc, fb, B, 2)
+        lane_feat = lanes_ref[0:1, :]
+        lane_bin = lanes_ref[1:2, :]
+        lam = scal_ref[0, 0]
+        mcw = scal_ref[0, 1]
+        last = scal_ref[0, 2] - 1.0
+        # feature subset (and this wrapper's padded features), and no split
+        # at the last valid bin — it sends every row left
+        lane_ok = (fmask_ref[...] > 0) & (lane_bin < last)
+        g, h = acc_g[...], acc_h[...]
         if subtract:
-            # accumulated = the SMALLER child of each sibling pair; derive
-            # the bigger one from the cached parent, then interleave back
-            # into heap order (node 2p, 2p+1): n_acc == n_nodes // 2
-            big = parent_ref[...] - hist
-            sil = (sil_ref[...] > 0)[:, :, None, None]   # (n_acc, 1, 1, 1)
-            left = jnp.where(sil, hist, big)
-            right = jnp.where(sil, big, hist)
-            hist = jnp.stack([left, right], axis=1).reshape(
-                n_nodes, block_f, n_bins, 2)
-        if hist_ref is not None:
-            hist_ref[...] = hist
-        # ---- in-kernel split scan (mirrors ref.split_scan_ref) ----------
-        gl = jnp.cumsum(hist[..., 0], axis=-1)           # (N, fb, B)
-        hl = jnp.cumsum(hist[..., 1], axis=-1)
+            # accumulated = the SMALLER child of each sibling pair; the
+            # other is parent − small. Side 0 = left children (node 2p),
+            # side 1 = right (node 2p+1)
+            sil = sil_ref[...] > 0                       # (N, 1)
+            gb = parent_ref[0] - g
+            hb = parent_ref[1] - h
+            sides = ((jnp.where(sil, g, gb), jnp.where(sil, h, hb)),
+                     (jnp.where(sil, gb, g), jnp.where(sil, hb, h)))
+        else:
+            sides = ((g, h),)
+        for s, (gs, hs) in enumerate(sides):
+            rows = pl.ds(s * n_pad, n_pad)
+            if hist_ref is not None:
+                hist_ref[0, s] = gs
+                hist_ref[1, s] = hs
 
-        @pl.when(fi == 0)
-        def _totals():
-            # node totals come from feature 0's cumsum tail (the oracle's
-            # gl[:, :1, -1:]); feature block 0 owns feature 0, so stash them
-            # in scratch for every later feature block's gain formula
-            tot[...] = jnp.stack([gl[:, 0, -1], hl[:, 0, -1]], axis=-1)
+            @pl.when(fi == 0)
+            def _totals():
+                # node totals from feature 0 (the oracle's convention);
+                # block 0 owns it, later blocks reuse the stash
+                f0 = lane_feat == 0.0
+                tot_g[rows, :] = jnp.sum(jnp.where(f0, gs, 0.0), axis=1,
+                                         keepdims=True)
+                tot_h[rows, :] = jnp.sum(jnp.where(f0, hs, 0.0), axis=1,
+                                         keepdims=True)
 
-        lam = lam_ref[0, 0]
-        mcw = mcw_ref[0, 0]
-        gt = tot[:, 0][:, None, None]
-        ht = tot[:, 1][:, None, None]
-        gr = gt - gl
-        hr = ht - hl
-        gain = gl**2 / (hl + lam) + gr**2 / (hr + lam) - gt**2 / (ht + lam)
-        ok = (hl >= mcw) & (hr >= mcw)
-        # fmask covers the caller's feature subset AND the features this
-        # wrapper padded on — a padded column's garbage gain must never win
-        ok &= (fmask_ref[...][0] > 0)[None, :, None]
-        last = blim_ref[0, 0] - 1
-        ok &= jax.lax.broadcasted_iota(
-            jnp.int32, (n_nodes, block_f, n_bins), 2) < last
-        gain = jnp.where(ok, gain, -jnp.inf)
-        flat = gain.reshape(n_nodes, block_f * n_bins)
-        loc_gain = jnp.max(flat, axis=-1)[:, None]       # (N, 1)
-        loc_idx = jnp.argmax(flat, axis=-1)[:, None]     # first max in block
-        loc_feat = (fi * block_f + loc_idx // n_bins).astype(jnp.int32)
-        loc_split = (loc_idx % n_bins).astype(jnp.int32)
+            gl = _segment_cumsum(gs, lane_bin, n_bins)
+            hl = _segment_cumsum(hs, lane_bin, n_bins)
+            gt = tot_g[rows, :]
+            ht = tot_h[rows, :]
+            gr = gt - gl
+            hr = ht - hl
+            gain = gl * gl / (hl + lam) + gr * gr / (hr + lam) - gt * gt / (ht + lam)
+            ok = lane_ok & (hl >= mcw) & (hr >= mcw)
+            gain = jnp.where(ok, gain, -jnp.inf)
+            loc_gain = jnp.max(gain, axis=1, keepdims=True)          # (N, 1)
+            # first maximum in lane order = lowest (feature, bin)
+            tie = gain == loc_gain
+            big = jnp.float32(1e9)
+            loc_feat = jnp.min(jnp.where(tie, lane_feat, big), axis=1,
+                               keepdims=True)
+            loc_split = jnp.min(
+                jnp.where(tie & (lane_feat == loc_feat), lane_bin, big),
+                axis=1, keepdims=True)
+            loc_feat = loc_feat.astype(jnp.int32)
+            loc_split = loc_split.astype(jnp.int32)
 
-        @pl.when(fi == 0)
-        def _first():
-            bg_ref[...] = loc_gain
-            bf_ref[...] = loc_feat
-            bs_ref[...] = loc_split
+            @pl.when(fi == 0)
+            def _first():
+                bg_ref[rows, :] = loc_gain
+                bf_ref[rows, :] = loc_feat
+                bs_ref[rows, :] = loc_split
 
-        @pl.when(fi > 0)
-        def _combine():
-            # strict > keeps the earlier feature block on ties — the global
-            # flattened first-argmax the XLA fallback computes
-            better = loc_gain > bg_ref[...]
-            bg_ref[...] = jnp.where(better, loc_gain, bg_ref[...])
-            bf_ref[...] = jnp.where(better, loc_feat, bf_ref[...])
-            bs_ref[...] = jnp.where(better, loc_split, bs_ref[...])
-
-
-def _level_kernel_hist(
-    bins_ref, node_ref, gh_ref, sil_ref, parent_ref, fmask_ref,
-    lam_ref, mcw_ref, blim_ref, hist_ref, bg_ref, bf_ref, bs_ref,
-    acc_g, acc_h, tot, **kw,
-):
-    _level_body(bins_ref, node_ref, gh_ref, sil_ref, parent_ref, fmask_ref,
-                lam_ref, mcw_ref, blim_ref, hist_ref, bg_ref, bf_ref, bs_ref,
-                acc_g, acc_h, tot, **kw)
-
-
-def _level_kernel_nohist(
-    bins_ref, node_ref, gh_ref, sil_ref, parent_ref, fmask_ref,
-    lam_ref, mcw_ref, blim_ref, bg_ref, bf_ref, bs_ref,
-    acc_g, acc_h, tot, **kw,
-):
-    _level_body(bins_ref, node_ref, gh_ref, sil_ref, parent_ref, fmask_ref,
-                lam_ref, mcw_ref, blim_ref, None, bg_ref, bf_ref, bs_ref,
-                acc_g, acc_h, tot, **kw)
+            @pl.when(fi > 0)
+            def _combine():
+                prev = bg_ref[rows, :]
+                better = loc_gain > prev
+                bg_ref[rows, :] = jnp.where(better, loc_gain, prev)
+                bf_ref[rows, :] = jnp.where(better, loc_feat, bf_ref[rows, :])
+                bs_ref[rows, :] = jnp.where(better, loc_split, bs_ref[rows, :])
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_nodes", "n_bins", "block_rows", "block_features",
-                     "interpret", "return_hist"),
+    static_argnames=("n_nodes", "n_bins", "interpret", "return_hist"),
 )
 def fused_level_split_tpu(
     bins: jax.Array,
@@ -339,8 +295,6 @@ def fused_level_split_tpu(
     feat_mask: jax.Array | None = None,
     parent_hist: jax.Array | None = None,
     small_is_left: jax.Array | None = None,
-    block_rows: int | None = None,
-    block_features: int | None = None,
     interpret: bool = False,
     return_hist: bool = True,
 ):
@@ -357,93 +311,127 @@ def fused_level_split_tpu(
     histograms and derives siblings as ``parent − small``.
 
     ``lam``/``min_child_weight`` may be traced 0-d arrays, ``bin_limit`` a
-    traced int — they ride in SMEM as (1, 1) scalars. Returns
-    ``(hist | None, best_gain, best_feat, best_split)``; ``hist`` is trimmed
-    of feature padding, the per-node bests are (n_nodes,) arrays.
+    traced int — they ride in SMEM. Returns ``(hist | None, best_gain,
+    best_feat, best_split)``: ``hist`` is ``(n_nodes, F, B, 2)``, the
+    per-node bests are ``(n_nodes,)`` arrays. ``n_bins`` must not exceed
+    :data:`MAX_KERNEL_BINS`.
     """
+    if n_bins > MAX_KERNEL_BINS:
+        raise ValueError(f"n_bins={n_bins} > {MAX_KERNEL_BINS}: bin ids "
+                         "would not be exact in bf16")
     r, f = bins.shape
     subtract = parent_hist is not None
-    n_acc = n_nodes // 2 if subtract else n_nodes
-    picked_f, picked_r = pick_tiles(f, n_bins, r, n_nodes)
-    block_rows = picked_r if block_rows is None else max(1, min(block_rows, r))
-    if not interpret and block_rows < 8:
-        block_rows = 8                        # Mosaic f32 sublane minimum
-    block_features = picked_f if block_features is None else min(block_features, f)
-    pad_r = (-r) % block_rows
-    pad_f = (-f) % block_features
-    bins_p = jnp.pad(bins, ((0, pad_r), (0, pad_f)))
-    node_p = jnp.pad(node.astype(jnp.int32), (0, pad_r), constant_values=n_acc)
-    gh = jnp.pad(
-        jnp.stack([grad, hess], axis=-1).astype(jnp.float32), ((0, pad_r), (0, 0))
-    )
-    fm = jnp.ones((f,), jnp.int32) if feat_mask is None else feat_mask.astype(jnp.int32)
-    fm_p = jnp.pad(fm[None, :], ((0, 0), (0, pad_f)))    # pad features: masked
-    lam_s = jnp.asarray(lam, jnp.float32).reshape(1, 1)
-    mcw_s = jnp.asarray(min_child_weight, jnp.float32).reshape(1, 1)
-    blim_s = jnp.asarray(
-        n_bins if bin_limit is None else bin_limit, jnp.int32).reshape(1, 1)
+    n_sides = 2 if subtract else 1
+    n_acc = n_nodes // n_sides
+    n_pad = _round_up(n_acc, _SUBLANES)
+    block_features, block_rows = pick_tiles(f, n_bins, r, n_nodes)
+    if not interpret:
+        # lane-major (1, rows) blocks: a multiple of 128 rows per step
+        block_rows = _round_up(block_rows, _LANES)
+    fp = _round_up(f, block_features)
+    w = block_features * n_bins
+    rp = _round_up(r, block_rows)
+    pad_r = rp - r
+    f32 = jnp.float32
+
+    group = _bins_group(f, block_features)
+    per_group = group // block_features
+    bins_p = jnp.pad(bins.astype(jnp.bfloat16),
+                     ((0, pad_r), (0, _round_up(fp, group) - f)))
+    node_p = jnp.pad(node.astype(jnp.int32), (0, pad_r),
+                     constant_values=n_pad)[None, :]
+    g_p = jnp.pad(grad.astype(f32), (0, pad_r))[None, :]
+    h_p = jnp.pad(hess.astype(f32), (0, pad_r))[None, :]
+    lane = jnp.arange(fp * n_bins, dtype=jnp.int32)
+    lanes = jnp.stack([lane // n_bins, lane % n_bins]).astype(f32)  # (2, Fp·B)
+    fm = (jnp.ones((f,), f32) if feat_mask is None
+          else feat_mask.astype(f32))
+    fmask = jnp.repeat(jnp.pad(fm, (0, fp - f)), n_bins)[None, :]
+    scal = jnp.stack([
+        jnp.asarray(lam, f32), jnp.asarray(min_child_weight, f32),
+        jnp.asarray(n_bins if bin_limit is None else bin_limit, f32),
+    ])[None, :]
     if subtract:
-        sil = small_is_left.astype(jnp.int32)[:, None]   # (n_acc, 1)
-        parent_p = jnp.pad(parent_hist.astype(jnp.float32),
-                           ((0, 0), (0, pad_f), (0, 0), (0, 0)))
-        sil_spec = pl.BlockSpec((n_acc, 1), lambda fi, ri: (0, 0))
-        parent_spec = pl.BlockSpec(
-            (n_acc, block_features, n_bins, 2), lambda fi, ri: (0, fi, 0, 0))
+        sil = jnp.pad(small_is_left.astype(f32), (0, n_pad - n_acc))[:, None]
+        planes = jnp.moveaxis(parent_hist.astype(f32), 3, 0)     # (2, N, F, B)
+        parent = jnp.pad(planes, ((0, 0), (0, n_pad - n_acc), (0, fp - f),
+                                  (0, 0))).reshape(2, n_pad, fp * n_bins)
+        sil_spec = pl.BlockSpec((n_pad, 1), lambda fi, ri: (0, 0))
+        parent_spec = pl.BlockSpec((2, n_pad, w), lambda fi, ri: (0, 0, fi))
     else:
-        sil = jnp.zeros((1, 1), jnp.int32)
-        parent_p = jnp.zeros((1, 1, 1, 1), jnp.float32)
-        sil_spec = pl.BlockSpec((1, 1), lambda fi, ri: (0, 0))
-        parent_spec = pl.BlockSpec((1, 1, 1, 1), lambda fi, ri: (0, 0, 0, 0))
-    rp, fp = bins_p.shape
+        sil = jnp.zeros((_SUBLANES, 1), f32)
+        parent = jnp.zeros((1, _SUBLANES, _LANES), f32)
+        sil_spec = pl.BlockSpec((_SUBLANES, 1), lambda fi, ri: (0, 0))
+        parent_spec = pl.BlockSpec((1, _SUBLANES, _LANES),
+                                   lambda fi, ri: (0, 0, 0))
     grid = (fp // block_features, rp // block_rows)
-    kernel = _level_kernel_hist if return_hist else _level_kernel_nohist
+    row_vec = pl.BlockSpec((1, block_rows), lambda fi, ri: (0, ri))
+    lane_spec = lambda k: pl.BlockSpec((k, w), lambda fi, ri: (0, fi))  # noqa: E731
+    best_spec = pl.BlockSpec((n_sides * n_pad, 1), lambda fi, ri: (0, 0))
     out_shape = [
-        jax.ShapeDtypeStruct((n_nodes, 1), jnp.float32),
-        jax.ShapeDtypeStruct((n_nodes, 1), jnp.int32),
-        jax.ShapeDtypeStruct((n_nodes, 1), jnp.int32),
+        jax.ShapeDtypeStruct((n_sides * n_pad, 1), f32),
+        jax.ShapeDtypeStruct((n_sides * n_pad, 1), jnp.int32),
+        jax.ShapeDtypeStruct((n_sides * n_pad, 1), jnp.int32),
     ]
-    best_spec = pl.BlockSpec((n_nodes, 1), lambda fi, ri: (0, 0))
     out_specs = [best_spec, best_spec, best_spec]
     if return_hist:
-        out_shape.insert(0, jax.ShapeDtypeStruct((n_nodes, fp, n_bins, 2),
-                                                 jnp.float32))
-        out_specs.insert(0, pl.BlockSpec(
-            (n_nodes, block_features, n_bins, 2), lambda fi, ri: (0, fi, 0, 0)))
-    smem_scalar = pl.BlockSpec((1, 1), lambda fi, ri: (0, 0),
-                               memory_space=pltpu.SMEM)
+        out_shape.insert(0, jax.ShapeDtypeStruct(
+            (2, n_sides, n_pad, fp * n_bins), f32))
+        out_specs.insert(0, pl.BlockSpec((2, n_sides, n_pad, w),
+                                         lambda fi, ri: (0, 0, 0, fi)))
     out = pl.pallas_call(
         functools.partial(
-            kernel,
-            n_acc=n_acc,
-            n_nodes=n_nodes,
-            n_bins=n_bins,
-            block_f=block_features,
-            n_rblocks=grid[1],
-            subtract=subtract,
-        ),
+            _level_kernel, n_pad=n_pad, n_bins=n_bins,
+            n_rblocks=grid[1], per_group=per_group, subtract=subtract,
+            return_hist=return_hist),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_rows, block_features), lambda fi, ri: (ri, fi)),
-            pl.BlockSpec((block_rows, 1), lambda fi, ri: (ri, 0)),
-            pl.BlockSpec((block_rows, 2), lambda fi, ri: (ri, 0)),
-            sil_spec,
-            parent_spec,
-            pl.BlockSpec((1, block_features), lambda fi, ri: (0, fi)),
-            smem_scalar,
-            smem_scalar,
-            smem_scalar,
+            pl.BlockSpec((block_rows, group),
+                         lambda fi, ri: (ri, fi // per_group)),
+            row_vec, row_vec, row_vec,
+            lane_spec(2), lane_spec(1),
+            sil_spec, parent_spec,
+            pl.BlockSpec((1, 3), lambda fi, ri: (0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((n_acc, block_features * n_bins), jnp.float32),
-            pltpu.VMEM((n_acc, block_features * n_bins), jnp.float32),
-            pltpu.VMEM((n_nodes, 2), jnp.float32),
+            pltpu.VMEM((group, w), jnp.bfloat16),
+            pltpu.VMEM((n_pad, w), f32),
+            pltpu.VMEM((n_pad, w), f32),
+            pltpu.VMEM((n_sides * n_pad, 1), f32),
+            pltpu.VMEM((n_sides * n_pad, 1), f32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(bins_p, node_p[:, None], gh, sil, parent_p, fm_p, lam_s, mcw_s, blim_s)
+    )(bins_p, node_p, g_p, h_p, lanes, fmask, sil, parent, scal)
+
+    def heap(x):
+        # (sides·N_pad, 1) → (n_nodes,) in heap order: node = n_sides·p + s
+        return x.reshape(n_sides, n_pad)[:, :n_acc].T.reshape(-1)
+
+    bg, bf, bs = (heap(o) for o in out[-3:])
+    hist = None
     if return_hist:
-        hist, bg, bf, bs = out
-        return hist[:, :f], bg[:, 0], bf[:, 0], bs[:, 0]
-    bg, bf, bs = out
-    return None, bg[:, 0], bf[:, 0], bs[:, 0]
+        h5 = out[0].reshape(2, n_sides, n_pad, fp, n_bins)[:, :, :n_acc, :f]
+        hist = h5.transpose(2, 1, 3, 4, 0).reshape(n_nodes, f, n_bins, 2)
+    return hist, bg, bf, bs
+
+
+def histogram_tpu(
+    bins: jax.Array,
+    grad: jax.Array,
+    hess: jax.Array,
+    node: jax.Array,
+    *,
+    n_nodes: int,
+    n_bins: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """Per-(node, feature, bin) grad/hess sums; see ``histogram_ref``.
+    The fused level kernel with its decisions dropped."""
+    hist, _, _, _ = fused_level_split_tpu(
+        bins, grad, hess, node, n_nodes=n_nodes, n_bins=n_bins, lam=1.0,
+        min_child_weight=0.0, interpret=interpret)
+    return hist

@@ -89,6 +89,18 @@ def rwkv6(r, k, v, w, u, s0=None, *, chunk=64, force=None):
     return _ref.rwkv6_ref(r, k, v, w, u, s0)
 
 
+def _use_level_kernel(force, n_bins: int) -> bool:
+    """Whether a GBDT level (or histogram) runs the Pallas kernel:
+    ``force="kernel"`` always (interpret mode off the chip), and by default
+    on TPU — except above ``MAX_KERNEL_BINS`` bins, whose ids the kernel's
+    bf16 transport cannot hold exactly; those take the XLA path."""
+    from repro.kernels.histogram import MAX_KERNEL_BINS
+
+    if force == "kernel":
+        return True
+    return force is None and _on_tpu() and n_bins <= MAX_KERNEL_BINS
+
+
 def _histogram_scatter(bins, grad, hess, node, n_nodes, n_bins):
     """XLA path: scatter-add formulation — O(R·F) adds, fast on CPU."""
     r, f = bins.shape
@@ -109,12 +121,11 @@ def histogram(bins, grad, hess, node, *, n_nodes, n_bins, force=None):
     Training no longer calls this directly — ``build_tree`` routes through
     :func:`level_split`, which fuses the split scan in (and threads its own
     ``force``); this stays the standalone histogram entry point for tests
-    and the tile sweep.
+    and the histogram smoke bench.
     """
     if force == "ref":
         return _ref.histogram_ref(bins, grad, hess, node, n_nodes, n_bins)
-    use_kernel = force == "kernel" or (force is None and _on_tpu())
-    if use_kernel:
+    if _use_level_kernel(force, n_bins):
         from repro.kernels.histogram import histogram_tpu
 
         return histogram_tpu(
@@ -122,6 +133,19 @@ def histogram(bins, grad, hess, node, *, n_nodes, n_bins, force=None):
             interpret=not _on_tpu(),
         )
     return _histogram_scatter(bins, grad, hess, node, n_nodes, n_bins)
+
+
+def _row_cumsum(x, block: int = 1024):
+    """Inclusive int32 prefix sum over a long row vector, in two levels
+    (within blocks, then over block totals). The TPU compiler lowers a flat
+    ``cumsum`` to one reduce-window as long as the vector and takes over ten
+    seconds per call site at a million rows; two short windows compile at
+    once. Integer sums, so the result equals ``jnp.cumsum`` exactly."""
+    n = x.shape[0]
+    blocks = jnp.pad(x.astype(jnp.int32), (0, (-n) % block)).reshape(-1, block)
+    inner = jnp.cumsum(blocks, axis=1)
+    carry = jnp.cumsum(inner[:, -1]) - inner[:, -1]
+    return (inner + carry[:, None]).reshape(-1)[:n]
 
 
 def _plan_smaller_child(node, n_nodes, n_rows):
@@ -142,7 +166,7 @@ def _plan_smaller_child(node, n_nodes, n_rows):
     is_small = jnp.stack([small_is_left, ~small_is_left], axis=1).reshape(-1)
     row_small = is_small[node]
     cap = n_rows // 2
-    pos = jnp.cumsum(row_small) - 1          # stable slot of each small row
+    pos = _row_cumsum(row_small) - 1         # stable slot of each small row
     slot = jnp.where(row_small, pos, cap)    # cap = out of bounds → dropped
     idx = jnp.zeros((cap,), jnp.int32).at[slot].set(jnp.arange(n_rows))
     valid = jnp.arange(cap) < row_small.sum()
@@ -215,9 +239,10 @@ def level_split(
     pair is accumulated from rows, the sibling is ``parent − small``. The
     XLA fallback's DIRECT mode is op-for-op the pre-fusion ``build_tree``
     sequence (``_histogram_scatter`` + ``ref.split_scan_ref``), so CPU
-    split decisions are bit-identical to the historical path; subtraction
-    reproduces those decisions (see DESIGN.md §3.8 for the exactness
-    argument). ``force`` matches ``ops`` conventions and is threaded by
+    split decisions are bit-identical to the historical path. Subtraction
+    rounds ``parent − small`` differently and may flip a near-tie; its
+    decisions keep the near-tie contract of ``ref.assert_split_decisions``
+    (DESIGN.md §3.8). ``force`` matches ``ops`` conventions and is threaded by
     ``build_tree`` so tests can pin a backend end to end.
 
     With ``axis_name`` the call runs in a per-shard SPMD view (row-sharded
@@ -238,7 +263,7 @@ def level_split(
             min_child_weight=min_child_weight, bin_limit=bin_limit,
             feat_mask=feat_mask)
         return (hist if return_hist else None), bg, bf, bs
-    use_kernel = force == "kernel" or (force is None and _on_tpu())
+    use_kernel = _use_level_kernel(force, n_bins)
     subtract = parent_hist is not None and n_nodes > 1
     if subtract:
         sil, idx, valid = _plan_smaller_child(node, n_nodes, bins.shape[0])
@@ -248,6 +273,11 @@ def level_split(
         if use_kernel:
             from repro.kernels.histogram import fused_level_split_tpu
 
+            # materialize the compacted rows: fused into the kernel's input
+            # padding, the vmapped gathers took the v5e compiler ~25 s per
+            # level of a fused batch, against ~4 s
+            sbins, sg, sh, snode = jax.lax.optimization_barrier(
+                (sbins, sg, sh, snode))
             return fused_level_split_tpu(
                 sbins, sg, sh, snode, n_nodes=n_nodes, n_bins=n_bins,
                 lam=lam, min_child_weight=min_child_weight,

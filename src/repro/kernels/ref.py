@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = [
     "attention_ref",
@@ -24,6 +25,8 @@ __all__ = [
     "rglru_ref",
     "rwkv6_ref",
     "histogram_ref",
+    "split_gains_ref",
+    "assert_split_decisions",
     "split_scan_ref",
     "level_split_ref",
 ]
@@ -321,6 +324,68 @@ def histogram_ref(
     return jnp.einsum("rn,rfbt->nfbt", node_oh, weighted)
 
 
+def split_gains_ref(hist: jax.Array, *, lam, min_child_weight, n_bins: int,
+                    bin_limit=None, feat_mask: jax.Array | None = None,
+                    ) -> jax.Array:
+    """Gain of every candidate split, ``(n_nodes, F, B)``; ``-inf`` where
+    the split is not admissible (child weight, feature mask, bin limit)."""
+    gl = jnp.cumsum(hist[..., 0], axis=-1)              # (N, F, B) left sums
+    hl = jnp.cumsum(hist[..., 1], axis=-1)
+    gt = gl[:, :1, -1:]                                  # (N, 1, 1) node totals
+    ht = hl[:, :1, -1:]
+    gr = gt - gl
+    hr = ht - hl
+    gain = gl**2 / (hl + lam) + gr**2 / (hr + lam) - gt**2 / (ht + lam)
+    ok = (hl >= min_child_weight) & (hr >= min_child_weight)
+    if feat_mask is not None:
+        ok &= feat_mask[None, :, None]
+    # splitting at the last bin sends every row left — not a real split
+    last = n_bins - 1 if bin_limit is None else bin_limit - 1
+    ok &= jnp.arange(n_bins)[None, None, :] < last
+    return jnp.where(ok, gain, -jnp.inf)
+
+
+def assert_split_decisions(hist, best_gain, best_feat, best_split, *, lam,
+                           min_child_weight, n_bins: int, bin_limit=None,
+                           feat_mask=None, rtol: float = 1e-4,
+                           atol: float = 1e-4) -> None:
+    """The split-decision contract of every ``level_split`` path that sums
+    in another order than the oracle (DESIGN.md §3.8), checked against the
+    REFERENCE histogram ``hist`` (``histogram_ref`` of the same rows).
+
+    Adjacent thresholds with no rows between them give the same partition,
+    so their gains tie in exact arithmetic and rounding picks the winner.
+    So per node: the chosen ``(best_feat, best_split)``, evaluated on
+    ``hist``, must be admissible and its gain must equal the best gain on
+    ``hist`` within tolerance; the reported ``best_gain`` must match that
+    best too (both ``-inf`` where no split is admissible). The tolerance
+    scales with the parent term ``G²/(H+λ)``, whose cancellation bounds the
+    gain's rounding error. Raises ``AssertionError`` naming the nodes."""
+    hist = np.asarray(hist, np.float32)
+    gain = np.asarray(split_gains_ref(
+        jnp.asarray(hist), lam=lam, min_child_weight=min_child_weight,
+        n_bins=n_bins, bin_limit=bin_limit, feat_mask=feat_mask))
+    n = gain.shape[0]
+    best = gain.reshape(n, -1).max(axis=1)
+    chosen = gain[np.arange(n), np.asarray(best_feat), np.asarray(best_split)]
+    gt, ht = hist[:, 0, :, 0].sum(axis=1), hist[:, 0, :, 1].sum(axis=1)
+    tol = atol + rtol * np.maximum(np.abs(np.where(np.isfinite(best), best, 0)),
+                                   gt * gt / np.maximum(ht + lam, 1e-30))
+    none = best == -np.inf
+    reported = np.asarray(best_gain, np.float32)
+    with np.errstate(invalid="ignore"):
+        choice_ok = none | (np.isfinite(chosen) & (best - chosen <= tol))
+        gain_ok = np.where(none, reported == -np.inf,
+                           np.abs(reported - best) <= tol)
+    bad = np.flatnonzero(~(choice_ok & gain_ok))
+    if bad.size:
+        raise AssertionError(
+            f"split decisions off at nodes {bad[:8].tolist()}: chosen gain "
+            f"{chosen[bad[:8]].tolist()}, reported {reported[bad[:8]].tolist()}"
+            f", reference best {best[bad[:8]].tolist()}, tol "
+            f"{tol[bad[:8]].tolist()}")
+
+
 def split_scan_ref(
     hist: jax.Array,
     *,
@@ -343,20 +408,9 @@ def split_scan_ref(
     cumsum tail (every feature's bins sum to the same node total).
     """
     n_nodes, f = hist.shape[0], hist.shape[1]
-    gl = jnp.cumsum(hist[..., 0], axis=-1)              # (N, F, B) left sums
-    hl = jnp.cumsum(hist[..., 1], axis=-1)
-    gt = gl[:, :1, -1:]                                  # (N, 1, 1) node totals
-    ht = hl[:, :1, -1:]
-    gr = gt - gl
-    hr = ht - hl
-    gain = gl**2 / (hl + lam) + gr**2 / (hr + lam) - gt**2 / (ht + lam)
-    ok = (hl >= min_child_weight) & (hr >= min_child_weight)
-    if feat_mask is not None:
-        ok &= feat_mask[None, :, None]
-    # splitting at the last bin sends every row left — not a real split
-    last = n_bins - 1 if bin_limit is None else bin_limit - 1
-    ok &= jnp.arange(n_bins)[None, None, :] < last
-    gain = jnp.where(ok, gain, -jnp.inf)
+    gain = split_gains_ref(hist, lam=lam, min_child_weight=min_child_weight,
+                           n_bins=n_bins, bin_limit=bin_limit,
+                           feat_mask=feat_mask)
     flat = gain.reshape(n_nodes, f * n_bins)
     best = jnp.argmax(flat, axis=-1)                     # first max wins ties
     best_gain = jnp.take_along_axis(flat, best[:, None], axis=-1)[:, 0]

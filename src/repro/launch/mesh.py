@@ -3,22 +3,13 @@
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — the dry-run must set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first init.
-
-``AxisType`` only exists in newer jax; on older installs ``jax.make_mesh``
-takes no ``axis_types`` argument and every axis is implicitly Auto, so
-:func:`compat_make_mesh` degrades gracefully instead of failing at import.
 """
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.4.38
-    from jax.sharding import AxisType
-except ImportError:  # older jax: no explicit axis types (all axes are Auto)
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 __all__ = [
     "compat_make_mesh",
@@ -29,11 +20,9 @@ __all__ = [
 
 
 def compat_make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where the installed jax has them."""
-    if AxisType is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis Auto (GSPMD-partitioned)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -20,6 +20,7 @@ Two workloads:
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import repro.tabular  # noqa: F401  (registers the four estimators)
@@ -36,6 +37,7 @@ from repro.core import (
 )
 from repro.data.pipeline import make_lm_stream
 from repro.data.synthetic import make_higgs_like, make_secom_like
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import count_params
 from repro.train import Trainer, make_optimizer
@@ -142,7 +144,12 @@ def run_tabular(args) -> int:
             print(f"  [{done}/{spec.n_grid_tasks}] exec {r.executor_id}: "
                   f"{r.task.key()} ({extras})")
     multi = session.multi_model()
+    # a task that raised is a failed search, not a smaller one
+    for r in multi.failures:
+        print(f"FAILED {r.task.key()}: {r.error}", file=sys.stderr)
     if not len(multi):
+        if multi.failures:
+            return 1
         print("nothing left to search (WAL already complete?)")
         return 0
     best = multi.best(valid, metric=args.metric)
@@ -183,7 +190,7 @@ def run_tabular(args) -> int:
           f"test {args.metric}={test_score:.4f} "
           f"(train {best.train_seconds:.2f}s + conv {best.convert_seconds:.2f}s "
           f"+ eval {best.eval_seconds:.3f}s, batch={best.batch_size})")
-    return 0
+    return 1 if multi.failures else 0
 
 
 def run_lm(args) -> int:
@@ -308,6 +315,7 @@ def main() -> int:
         p.error("--resume requires --wal")
     if args.tuner_arg and not args.tuner:
         p.error("--tuner-arg requires --tuner")
+    enable_compile_cache()
     return run_tabular(args) if args.workload == "tabular" else run_lm(args)
 
 

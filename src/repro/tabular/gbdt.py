@@ -120,13 +120,21 @@ def build_tree(
 
 
 def predict_margin(bins, feat, split, leaf_value, max_depth: int):
-    """Route binned rows through one heap-layout tree; returns (R,) margins."""
+    """Route binned rows through one heap-layout tree; returns (R,) margins.
+
+    Compile time on the TPU shapes this: each level looks (feat, split) up
+    in ONE packed gather, and materializes its node vector
+    (``optimization_barrier``). Two small-table gathers per level, fused
+    across levels, took the v5e compiler over 20 s for a depth-6 tree at
+    600k rows; this form compiles in under 2 s at every depth."""
     r = bins.shape[0]
     local = jnp.zeros((r,), jnp.int32)
+    table = jnp.stack([feat, split], axis=1)
     for level in range(max_depth):
-        g_idx = (1 << level) - 1 + local
-        row_bin = jnp.take_along_axis(bins, feat[g_idx][:, None], axis=1)[:, 0]
-        local = 2 * local + (row_bin > split[g_idx]).astype(jnp.int32)
+        fs = table[(1 << level) - 1 + local]
+        row_bin = jnp.take_along_axis(bins, fs[:, :1], axis=1)[:, 0]
+        local = jax.lax.optimization_barrier(
+            2 * local + (row_bin > fs[:, 1]).astype(jnp.int32))
     return leaf_value[local]
 
 
@@ -214,6 +222,20 @@ def batched_tree_margins(models, x, *, cache=None) -> np.ndarray:
     return out
 
 
+def _coarse_bins(bins, factor):
+    """``bins // factor`` for a traced ``factor``, in-graph. Integer division
+    by a traced divisor costs the TPU compiler tens of seconds per program at
+    a million rows, so the quotient is taken in f32 and then corrected with
+    integer products: f32 division need not be correctly rounded on every
+    backend, but it lands within one of the true quotient for ids and
+    factors far below 2^24, and one step each way makes it exact."""
+    factor = jnp.asarray(factor, bins.dtype)
+    q = jnp.floor(bins.astype(jnp.float32) / factor.astype(jnp.float32))
+    q = q.astype(bins.dtype)
+    q = q - (q * factor > bins).astype(bins.dtype)
+    return q + ((q + 1) * factor <= bins).astype(bins.dtype)
+
+
 def _fit_gbdt_core(
     bins, y, base, factor, bin_limit, n_rounds, depth_limit,
     eta, lam, gamma, min_child_weight, *, n_bins: int, rounds: int, max_depth: int,
@@ -230,7 +252,7 @@ def _fit_gbdt_core(
     ``depth_limit`` force sentinel splits, bins past ``bin_limit`` never win.
     """
     r = bins.shape[0]
-    cbins = bins // factor          # coarsen in-graph: factor is traced
+    cbins = _coarse_bins(bins, factor)
 
     def one_round(margin, r_idx):
         p = jax.nn.sigmoid(margin)
@@ -273,7 +295,7 @@ def _resume_gbdt_core(
     appends the exact trees a straight run would have grown. ``rounds`` is
     the UNPADDED increment — no masked tail whose ``+0.0`` margin adds could
     flip -0.0 bits between the chained and the straight run."""
-    cbins = bins // factor          # coarsen in-graph: factor is traced
+    cbins = _coarse_bins(bins, factor)
 
     def one_round(margin, r_idx):
         p = jax.nn.sigmoid(margin)

@@ -35,6 +35,8 @@ def run_subprocess(code: str, devices: int = 8) -> str:
         capture_output=True, text=True, timeout=600,
         env={
             "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
+            # the child must never reach for a chip its parent may hold
+            "JAX_PLATFORMS": "cpu",
             "PYTHONPATH": "src",
             "PATH": "/usr/bin:/bin",
             "HOME": "/root",

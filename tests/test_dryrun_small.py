@@ -25,6 +25,8 @@ def run_sub(code: str, devices: int = 8) -> str:
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=900,
         env={"XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
+             # the child must never reach for a chip its parent may hold
+             "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "HOME": "/root"},
     )
     assert res.returncode == 0, res.stderr[-3000:]

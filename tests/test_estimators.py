@@ -92,6 +92,18 @@ def test_quantized_bins_roundtrip_consistency(higgs_small):
         np.testing.assert_array_equal(lhs, rhs)
 
 
+def test_coarse_bins_equals_integer_division():
+    """The in-graph coarsening matches ``//`` for every 8-bit bin id and
+    every factor the 256-bin format can ask for, with a traced factor."""
+    from repro.tabular.gbdt import _coarse_bins
+
+    ids = jnp.arange(256, dtype=jnp.int32)
+    factors = jnp.arange(1, 257, dtype=jnp.int32)
+    got = jax.jit(jax.vmap(_coarse_bins, in_axes=(None, 0)))(ids, factors)
+    want = np.arange(256)[None, :] // np.arange(1, 257)[:, None]
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_mlp_cost_model_monotonic():
     est = get_estimator("mlp")
     small = est.estimate_cost({"network": "32", "steps": 100}, 1000, 28)

@@ -182,18 +182,27 @@ def test_histogram_conservation(rng):
 
 
 def test_histogram_tile_table_respects_vmem_budget():
-    """pick_tiles shrinks block_features as n_nodes grows: the two f32 VMEM
-    accumulators (2·N·bf·B·4 bytes) must stay inside the scratch budget at
-    every tree level, not just the shallow ones the sweep measured."""
-    from repro.kernels.histogram import _VMEM_SCRATCH_BUDGET, pick_tiles
+    """pick_tiles shrinks block_features as n_nodes grows: one grid step's
+    buffers (accumulators, histogram planes, scan temporaries) must stay
+    inside the VMEM budget at every tree level, not just the shallow ones,
+    and every feature block must be a whole number of 128-lane tiles that
+    reads one aligned column group of the bins."""
+    from repro.kernels.histogram import (_VMEM_BUDGET, _bins_group,
+                                         _vmem_bytes, pick_tiles)
 
-    for n_bins in (32, 64, 128, 256):
-        for n_nodes in (1, 8, 64, 512, 2048):
-            bf, br = pick_tiles(120, n_bins, 4800, n_nodes=n_nodes)
-            assert bf >= 1 and br >= 8
-            assert (bf == 1
-                    or 2 * n_nodes * bf * n_bins * 4 <= _VMEM_SCRATCH_BUDGET)
-    # deep level really does shrink vs the table default
+    for n_feat in (28, 120, 590):
+        for n_bins in (32, 64, 128, 256):
+            for n_nodes in (1, 8, 64, 512, 2048):
+                bf, br = pick_tiles(n_feat, n_bins, 4800, n_nodes=n_nodes)
+                assert bf >= 1 and br >= 8
+                assert (bf * n_bins) % 128 == 0
+                group = _bins_group(n_feat, bf)
+                assert group % bf == 0
+                assert group == -(-n_feat // bf) * bf or group % 128 == 0
+                assert (bf * n_bins == max(128, n_bins)
+                        or _vmem_bytes(bf, n_bins, n_nodes, br, group)
+                        <= _VMEM_BUDGET)
+    # deep level really does shrink vs the shallow default
     assert pick_tiles(120, 64, 4800, n_nodes=2048)[0] < \
         pick_tiles(120, 64, 4800, n_nodes=8)[0]
 
@@ -227,7 +236,7 @@ def test_pick_tiles_never_exceeds_rows(rng):
         _, br = pick_tiles(16, 64, n_rows)
         assert br == n_rows
     _, br = pick_tiles(16, 64, 4800)
-    assert br == 1024                      # table default untouched
+    assert br == 256                       # row-tile default untouched
     # and a 4-row histogram actually computes correctly through the kernel
     r, f, nb, nn = 4, 3, 8, 2
     bins = jnp.asarray(rng.integers(0, nb, size=(r, f)), jnp.int32)
@@ -255,6 +264,14 @@ def _level_fixture(rng, r, f, nb, nn):
     return bins, g, h, node
 
 
+def _assert_decisions(hist_ref, bg_ref, out, **scan):
+    """The split-decision contract shared with the chip parity check
+    (``ref.assert_split_decisions``, DESIGN.md §3.8)."""
+    np.testing.assert_array_equal(np.isfinite(np.asarray(out[1])),
+                                  np.isfinite(np.asarray(bg_ref)))
+    ref.assert_split_decisions(hist_ref, out[1], out[2], out[3], **scan)
+
+
 def _parent_of(bins, g, h, node, nn, nb):
     """Level-above histograms over the same rows (node // 2)."""
     return ops._histogram_scatter(bins, g, h, node // 2, nn // 2, nb)
@@ -265,28 +282,30 @@ def _parent_of(bins, g, h, node, nn, nb):
 @pytest.mark.parametrize("r,f,nb,nn", [
     (200, 5, 16, 1), (500, 7, 64, 4), (400, 12, 256, 4), (300, 9, 16, 32),
     (600, 3, 64, 32), (250, 6, 256, 32),
+    (64, 300, 256, 2),   # wide: feature blocks in aligned bins column groups
 ])
 def test_level_split_kernel_vs_ref(rng, r, f, nb, nn):
     bins, g, h, node = _level_fixture(rng, r, f, nb, nn)
-    kw = dict(n_nodes=nn, n_bins=nb, lam=1.0, min_child_weight=1.0)
-    hk, bgk, bfk, bsk = ops.level_split(bins, g, h, node, force="kernel", **kw)
+    scan = dict(n_bins=nb, lam=1.0, min_child_weight=1.0)
+    kw = dict(n_nodes=nn, **scan)
     hr, bgr, bfr, bsr = ops.level_split(bins, g, h, node, force="ref", **kw)
     hx, bgx, bfx, bsx = ops.level_split(bins, g, h, node, **kw)
-    np.testing.assert_allclose(np.asarray(hk), np.asarray(hr), atol=1e-4)
+    # the XLA direct path is op-for-op the oracle's scan: identical choices
     np.testing.assert_allclose(np.asarray(hx), np.asarray(hr), atol=1e-4)
-    for bf, bs in ((bfk, bsk), (bfx, bsx)):
-        assert bool((bf == bfr).all() and (bs == bsr).all())
-    finite = np.isfinite(np.asarray(bgr))
-    np.testing.assert_allclose(np.asarray(bgk)[finite], np.asarray(bgr)[finite],
-                               rtol=1e-4, atol=1e-4)
-    # subtraction modes (XLA + kernel) must reproduce the direct decisions
+    assert bool((bfx == bfr).all() and (bsx == bsr).all())
+    # the kernel (direct) and both subtraction paths sum in another order:
+    # same histograms within float tolerance, split choices under the
+    # near-tie contract
+    outs = [ops.level_split(bins, g, h, node, force="kernel", **kw)]
     if nn > 1:
         parent = _parent_of(bins, g, h, node, nn, nb)
-        for force in (None, "kernel"):
-            hs, _, bfs, bss = ops.level_split(
-                bins, g, h, node, parent_hist=parent, force=force, **kw)
-            np.testing.assert_allclose(np.asarray(hs), np.asarray(hr), atol=1e-4)
-            assert bool((bfs == bfr).all() and (bss == bsr).all())
+        outs += [ops.level_split(bins, g, h, node, parent_hist=parent,
+                                 force=force, **kw)
+                 for force in (None, "kernel")]
+    for out in outs:
+        np.testing.assert_allclose(np.asarray(out[0]), np.asarray(hr),
+                                   atol=1e-4)
+        _assert_decisions(hr, bgr, out, **scan)
 
 
 def test_level_split_traced_bin_limit(rng):
@@ -307,9 +326,10 @@ def test_level_split_traced_bin_limit(rng):
     for force in ("kernel", "ref", None):
         bg, bf, bs = make(force)(jnp.int32(16))
         assert bool((np.asarray(bs) < 15).all())
-    bg_k, bf_k, bs_k = make("kernel")(jnp.int32(16))
-    bg_r, bf_r, bs_r = make("ref")(jnp.int32(16))
-    assert bool((bf_k == bf_r).all() and (bs_k == bs_r).all())
+    hist = ref.histogram_ref(bins, g, h, node, 8, 64)
+    _assert_decisions(hist, make("ref")(jnp.int32(16))[0],
+                      (None, *make("kernel")(jnp.int32(16))),
+                      n_bins=64, lam=0.5, min_child_weight=1.0, bin_limit=16)
 
 
 def test_level_split_feat_mask(rng):
@@ -319,10 +339,14 @@ def test_level_split_feat_mask(rng):
     mask = jnp.asarray(np.arange(10) % 3 == 0)     # features 0,3,6,9 allowed
     kw = dict(n_nodes=8, n_bins=32, lam=1.0, min_child_weight=1.0,
               feat_mask=mask)
-    _, bg_r, bf_r, bs_r = ops.level_split(bins, g, h, node, force="ref", **kw)
+    h_r, bg_r, bf_r, bs_r = ops.level_split(bins, g, h, node, force="ref", **kw)
     for force in ("kernel", None):
-        _, bg, bf, bs = ops.level_split(bins, g, h, node, force=force, **kw)
-        assert bool((bf == bf_r).all() and (bs == bs_r).all())
+        out = ops.level_split(bins, g, h, node, force=force, **kw)
+        _, bg, bf, bs = out
+        if force is None:
+            assert bool((bf == bf_r).all() and (bs == bs_r).all())
+        _assert_decisions(h_r, bg_r, out, n_bins=32, lam=1.0,
+                          min_child_weight=1.0, feat_mask=mask)
         real = np.isfinite(np.asarray(bg))
         assert bool(np.asarray(mask)[np.asarray(bf)[real]].all())
 
@@ -398,7 +422,10 @@ def test_level_split_kernel_under_vmap(rng):
         gs, hs, nodes, lams)
     np.testing.assert_allclose(np.asarray(out_k[0]), np.asarray(out_r[0]),
                                atol=1e-4)
-    assert bool((out_k[2] == out_r[2]).all() and (out_k[3] == out_r[3]).all())
+    for i in range(b):
+        _assert_decisions(out_r[0][i], out_r[1][i],
+                          [o[i] for o in out_k], n_bins=nb, lam=float(lams[i]),
+                          min_child_weight=1.0)
 
 
 @pytest.mark.parametrize("depth,nb", [(1, 16), (3, 64), (6, 256), (6, 16)])
