@@ -26,7 +26,6 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import threading
-import time
 from collections import OrderedDict
 from typing import Any, Callable, Hashable, Mapping
 
@@ -34,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.tenancy import TenantLedger
+from repro.core.tracing import span
 
 __all__ = [
     "DenseMatrix",
@@ -408,17 +408,19 @@ class PreparedDataCache:
                 self.misses += 1       # misses = builds attempted
                 self._ledger.add("misses")
         if owner:
-            t0 = time.perf_counter()
-            try:
-                entry.value = builder()       # convert outside the lock
-            except BaseException as e:
-                entry.error = e
-                with self._lock:              # failed builds don't poison the key
-                    self._entries.pop(key, None)
-                entry.ready.set()
-                raise
-            entry.seconds = time.perf_counter() - t0
-            entry.nbytes = payload_nbytes(entry.value)
+            fmt = key[1] if isinstance(key, tuple) and len(key) > 1 else key
+            with span("repro.convert", format=str(fmt)) as sp:
+                try:
+                    entry.value = builder()   # convert outside the lock
+                except BaseException as e:
+                    entry.error = e
+                    with self._lock:          # failed builds don't poison the key
+                        self._entries.pop(key, None)
+                    entry.ready.set()
+                    raise
+                entry.nbytes = payload_nbytes(entry.value)
+                sp.set(bytes=entry.nbytes)
+            entry.seconds = sp.seconds
             with self._lock:
                 self._bytes += entry.nbytes
                 self.bytes_built += entry.nbytes

@@ -40,7 +40,6 @@ family's ``predict_proba`` uses — the naive ``1/(1+exp(-z))`` overflows
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Hashable, Sequence
 
 import numpy as np
@@ -48,6 +47,7 @@ import numpy as np
 from repro.core.data_format import DenseMatrix, is_sharded_payload, prepare_cached
 from repro.core.fusion import CompileCache
 from repro.core.results import METRICS, sharded_metric
+from repro.core.tracing import span
 
 __all__ = [
     "EvalPlan",
@@ -136,37 +136,43 @@ def evaluate_models(
     if not models or not all(isinstance(m, TrainedModel) for m in models):
         return [None] * len(models), 0.0
     cache = cache if cache is not None else _PREDICT_CACHE
-    t0 = time.perf_counter()
-    try:
-        entry, _conv_s, _built = prepare_cached(
-            plan.data, getattr(est, "eval_format", "eval_dense"),
-            cache=prepared_cache, placement=placement)
-        x = entry["x"]
-        sharded = is_sharded_payload(entry)
-        if sharded:
-            # prediction is row-local: score the flattened (S·Rs, F) block
-            # view, then reduce per-shard metric PARTIALS (§3.9) — no
-            # gathered prediction vector for decomposable metrics
-            n_shards, rows_per_shard = int(entry["_n_shards"]), x.shape[1]
-            x = x.reshape(n_shards * rows_per_shard, *x.shape[2:])
-        if len(models) > 1:
-            probs = type(models[0]).predict_proba_batched(models, x, cache=cache)
-        else:
-            probs = [models[0].predict_proba_jax(x, cache=cache)]
-        y = plan.data.y
-        if sharded:
-            n_rows = int(entry["_n_rows"])
-            valid = np.asarray(entry["_shard_valid"])
-            y_blocks = np.zeros(valid.shape, np.asarray(y).dtype)
-            y_blocks.reshape(-1)[:n_rows] = np.asarray(y).reshape(-1)
-            scores: list[float | None] = [
-                sharded_metric(plan.metric, y_blocks,
-                               np.asarray(p).reshape(valid.shape), valid, n_rows)
-                for p in probs]
-        else:
-            metric_fn = METRICS[plan.metric]
-            scores = [float(metric_fn(y, np.asarray(p))) for p in probs]
-    except Exception:
-        return [None] * len(models), 0.0
-    total = time.perf_counter() - t0
-    return scores, total / len(models)
+    with span("repro.eval", family=est.name, size=len(models)) as sp:
+        try:
+            entry, _conv_s, _built = prepare_cached(
+                plan.data, getattr(est, "eval_format", "eval_dense"),
+                cache=prepared_cache, placement=placement)
+            x = entry["x"]
+            sharded = is_sharded_payload(entry)
+            if sharded:
+                # prediction is row-local: score the flattened (S·Rs, F) block
+                # view, then reduce per-shard metric PARTIALS (§3.9) — no
+                # gathered prediction vector for decomposable metrics
+                n_shards, rows_per_shard = int(entry["_n_shards"]), x.shape[1]
+                x = x.reshape(n_shards * rows_per_shard, *x.shape[2:])
+            if len(models) > 1:
+                probs = type(models[0]).predict_proba_batched(models, x, cache=cache)
+            else:
+                probs = [models[0].predict_proba_jax(x, cache=cache)]
+            y = plan.data.y
+            if sharded:
+                n_rows = int(entry["_n_rows"])
+                valid = np.asarray(entry["_shard_valid"])
+                y_blocks = np.zeros(valid.shape, np.asarray(y).dtype)
+                y_blocks.reshape(-1)[:n_rows] = np.asarray(y).reshape(-1)
+
+                def score(p):
+                    return sharded_metric(plan.metric, y_blocks,
+                                          np.asarray(p).reshape(valid.shape),
+                                          valid, n_rows)
+            else:
+                metric_fn = METRICS[plan.metric]
+
+                def score(p):
+                    return float(metric_fn(y, np.asarray(p)))
+            # the host reduction alone: the probabilities are on the host
+            with span("repro.eval.metric", metric=plan.metric,
+                      size=len(models)):
+                scores: list[float | None] = [score(p) for p in probs]
+        except Exception:
+            return [None] * len(models), 0.0
+    return scores, sp.seconds / len(models)

@@ -64,6 +64,7 @@ from repro.core.interface import (
     run_prepared_resumable,
 )
 from repro.core.scheduler import Assignment
+from repro.core.tracing import span
 
 __all__ = ["LocalExecutorPool", "MeshSliceExecutorPool", "ShardGroup",
            "make_slices"]
@@ -74,7 +75,8 @@ _DYNAMIC_POLICIES = ("dynamic", "lpt_dynamic")
 def _run_fused_unit(unit: FusedBatch, data, eid: int,
                     cache: PreparedDataCache | None = None,
                     placement=None,
-                    validate: EvalPlan | None = None) -> list[TaskResult]:
+                    validate: EvalPlan | None = None,
+                    search: int = 0) -> list[TaskResult]:
     """Train a fused batch as ONE device program and unbatch into per-member
     results. Amortized accounting: each member's ``train_seconds`` is the
     batch total divided by the members actually run, and ``batch_size``
@@ -89,21 +91,24 @@ def _run_fused_unit(unit: FusedBatch, data, eid: int,
     (``split_at_buckets``) and each piece re-runs; an unsplittable piece
     degrades to solo member runs — so one poison config costs only its own
     result and every good member is salvaged. Task-level failure semantics
-    throughout: the executor survives."""
+    throughout: the executor survives. Each attempt is one ``repro.unit``
+    span of the search ``search``."""
     members = list(unit.tasks)
     est = get_estimator(unit.estimator)
     try:
-        models, total, conv = run_prepared_batched(
-            est, data, [m.params for m in members],
-            cache=cache, placement=placement)
+        with span("repro.unit", search=search, unit=unit.task_id,
+                  family=unit.estimator, size=len(members), executor=eid):
+            models, total, conv = run_prepared_batched(
+                est, data, [m.params for m in members],
+                cache=cache, placement=placement)
+            scores: list = [None] * len(members)
+            eval_per = 0.0
+            if validate is not None:
+                scores, eval_per = evaluate_models(
+                    est, models, validate, prepared_cache=cache,
+                    placement=placement)
         per = total / len(members)
         carrier = charge_carrier(members) if conv > 0 else -1
-        scores: list = [None] * len(members)
-        eval_per = 0.0
-        if validate is not None:
-            scores, eval_per = evaluate_models(
-                est, models, validate, prepared_cache=cache,
-                placement=placement)
         return [
             TaskResult(task=m, model=mod, train_seconds=per, executor_id=eid,
                        batch_size=len(members),
@@ -123,21 +128,15 @@ def _run_fused_unit(unit: FusedBatch, data, eid: int,
             for piece in pieces:
                 out.extend(_run_fused_unit(piece, data, eid, cache=cache,
                                            placement=placement,
-                                           validate=validate))
+                                           validate=validate, search=search))
             return out
         # single structural bucket: fall back to the singleton machinery —
         # run each member solo so only the culprit carries the error
         out = []
         for m in members:
             try:
-                s_est, model, secs, conv, rstate = _train_solo(
-                    m, data, cache=cache, placement=placement)
-                score, eval_s = _score_solo(s_est, model, validate, cache,
-                                            placement=placement)
-                out.append(TaskResult(task=m, model=model, train_seconds=secs,
-                                      executor_id=eid, convert_seconds=conv,
-                                      score=score, eval_seconds=eval_s,
-                                      resume_state=rstate))
+                out.append(_run_solo(m, data, eid, validate, cache=cache,
+                                     placement=placement, search=search))
             except ExecutorFailure:
                 raise
             except Exception as e2:
@@ -146,40 +145,37 @@ def _run_fused_unit(unit: FusedBatch, data, eid: int,
         return out
 
 
-def _train_solo(task, data, cache: PreparedDataCache | None = None,
-                placement=None):
-    """Train one solo task, dispatching :class:`RungTask`s through the
-    resumable path (DESIGN.md §3.6) so a promoted rung continues from its
-    carried state instead of retraining from scratch; plain tasks keep the
-    ``run_prepared`` path unchanged. Every solo call site (workers,
-    driver-inline leftovers, mesh slices, the multi-tenant service) goes
-    through here so rung semantics cannot diverge. Returns
-    ``(estimator, model, train_seconds, convert_seconds, resume_state)``."""
+def _run_solo(task, data, eid: int, validate: EvalPlan | None = None,
+              cache: PreparedDataCache | None = None, placement=None,
+              search: int = 0) -> TaskResult:
+    """Train one solo task and, with ``validate`` set, score its model
+    executor-side (§3.4), as one ``repro.unit`` span of the search
+    ``search``. :class:`RungTask`s go through the resumable path (DESIGN.md
+    §3.6) so a promoted rung continues from its carried state instead of
+    retraining from scratch; plain tasks take ``run_prepared``. Every solo
+    call site (workers, driver-inline leftovers, mesh slices, the
+    multi-tenant service) goes through here so rung and scoring semantics
+    cannot diverge. Training errors propagate to the caller."""
     est = get_estimator(task.estimator)
-    if isinstance(task, RungTask):
-        model, secs, conv, rstate = run_prepared_resumable(
-            est, data, task.params, budget=task.budget, state=task.state,
-            cache=cache, placement=placement)
-        return est, model, secs, conv, rstate
-    model, secs, conv = run_prepared(est, data, task.params,
-                                     cache=cache, placement=placement)
-    return est, model, secs, conv, None
-
-
-def _score_solo(est, model, validate: EvalPlan | None,
-                cache: PreparedDataCache | None,
-                placement=None) -> tuple[float | None, float]:
-    """Executor-side scoring of one task's model (§3.4); returns
-    ``(score, eval_seconds)`` — ``(None, 0.0)`` when scoring is off. The
-    shared solo half of what ``_run_fused_unit`` does for a whole batch;
-    every solo path (workers, driver-inline leftovers, mesh slices) goes
-    through here so the semantics cannot diverge."""
-    if validate is None:
-        return None, 0.0
-    scores, eval_s = evaluate_models(est, [model], validate,
-                                     prepared_cache=cache,
-                                     placement=placement)
-    return scores[0], eval_s
+    rstate = None
+    score, eval_s = None, 0.0
+    with span("repro.unit", search=search, unit=task.task_id,
+              family=task.estimator, size=1, executor=eid):
+        if isinstance(task, RungTask):
+            model, secs, conv, rstate = run_prepared_resumable(
+                est, data, task.params, budget=task.budget, state=task.state,
+                cache=cache, placement=placement)
+        else:
+            model, secs, conv = run_prepared(est, data, task.params,
+                                             cache=cache, placement=placement)
+        if validate is not None:
+            scores, eval_s = evaluate_models(est, [model], validate,
+                                             prepared_cache=cache,
+                                             placement=placement)
+            score = scores[0]
+    return TaskResult(task=task, model=model, train_seconds=secs,
+                      executor_id=eid, convert_seconds=conv, score=score,
+                      eval_seconds=eval_s, resume_state=rstate)
 
 
 class LocalExecutorPool:
@@ -344,7 +340,8 @@ class LocalExecutorPool:
                     batch_results = _run_fused_unit(sub, data, eid,
                                                     cache=self.prepared_cache,
                                                     placement=self._placement_token,
-                                                    validate=validate)
+                                                    validate=validate,
+                                                    search=assignment.search)
             except ExecutorFailure:
                 with results_lock:
                     in_flight.pop(unit.task_id, None)
@@ -393,16 +390,10 @@ class LocalExecutorPool:
             try:
                 if self.failure_hook is not None:
                     self.failure_hook(eid, task)  # may raise ExecutorFailure
-                est, model, secs, conv, rstate = _train_solo(
-                    task, data, cache=self.prepared_cache,
-                    placement=self._placement_token)
-                score, eval_s = _score_solo(est, model, validate,
-                                            self.prepared_cache,
-                                            placement=self._placement_token)
-                res = TaskResult(task=task, model=model, train_seconds=secs,
-                                 executor_id=eid, convert_seconds=conv,
-                                 score=score, eval_seconds=eval_s,
-                                 resume_state=rstate)
+                res = _run_solo(task, data, eid, validate,
+                                cache=self.prepared_cache,
+                                placement=self._placement_token,
+                                search=assignment.search)
             except ExecutorFailure:
                 with results_lock:
                     in_flight.pop(task.task_id, None)
@@ -719,7 +710,8 @@ class LocalExecutorPool:
                     for res in _run_fused_unit(sub, data, -1,
                                                cache=self.prepared_cache,
                                                placement=self._placement_token,
-                                               validate=validate):
+                                               validate=validate,
+                                               search=assignment.search):
                         if (not res.ok
                                 and self._retry.should_retry(res.task.task_id)):
                             self._retry.wait(res.task.task_id)
@@ -740,22 +732,19 @@ class LocalExecutorPool:
                                 break
                         continue
                     try:
-                        est, model, secs, conv, rstate = _train_solo(
-                            task, data, cache=self.prepared_cache,
-                            placement=self._placement_token)
-                        score, eval_s = _score_solo(est, model, validate,
-                                                    self.prepared_cache,
-                                                    placement=self._placement_token)
-                        res = TaskResult(task=task, model=model, train_seconds=secs,
-                                         executor_id=-1, convert_seconds=conv,
-                                         score=score, eval_seconds=eval_s,
-                                         resume_state=rstate)
-                        self.wal.record(WALRecord(task_id=task.task_id, key=task.key(),
-                                                  seconds=secs, executor_id=-1,
-                                                  score=score, convert_seconds=conv,
-                                                  eval_seconds=eval_s))
-                        if rstate is not None:
-                            self.wal.record_resume(task.task_id, rstate)
+                        res = _run_solo(task, data, -1, validate,
+                                        cache=self.prepared_cache,
+                                        placement=self._placement_token,
+                                        search=assignment.search)
+                        self.wal.record(WALRecord(
+                            task_id=task.task_id, key=task.key(),
+                            seconds=res.train_seconds, executor_id=-1,
+                            score=res.score,
+                            convert_seconds=res.convert_seconds,
+                            eval_seconds=res.eval_seconds))
+                        if res.resume_state is not None:
+                            self.wal.record_resume(task.task_id,
+                                                   res.resume_state)
                     except Exception as e:
                         if self._retry.should_retry(task.task_id):
                             self._retry.wait(task.task_id)
@@ -966,6 +955,7 @@ class MeshSliceExecutorPool:
         #: ``submit`` to re-queue (the pool is a serial generator, so the
         #: buffer needs no lock)
         self._pending_retry: list[TrainTask] = []
+        self._search = 0
 
     def _emit(self, res: TaskResult) -> TaskResult:
         if self.on_result is not None:
@@ -1034,34 +1024,30 @@ class MeshSliceExecutorPool:
         through the prepared cache under the slice's placement token, so
         each slice holds its own resident eval copy; a custom
         ``task_runner`` owns its payloads, so scoring is skipped."""
-        conv = 0.0
-        score, eval_s = None, 0.0
-        rstate = None
         try:
             if self.failure_hook is not None:
                 self.failure_hook(eid, task)  # may raise ExecutorFailure
             if self.task_runner is not None:
                 model, secs = self.task_runner(task, sl, data)
+                res = TaskResult(task=task, model=model, train_seconds=secs,
+                                 executor_id=eid)
             else:
-                est, model, secs, conv, rstate = _train_solo(
-                    task, data, cache=self.prepared_cache,
-                    placement=self._placement(sl))
-                score, eval_s = _score_solo(est, model, validate,
-                                            self.prepared_cache,
-                                            placement=self._placement(sl))
+                res = _run_solo(task, data, eid, validate,
+                                cache=self.prepared_cache,
+                                placement=self._placement(sl),
+                                search=self._search)
         except ExecutorFailure:
             raise
         except Exception as e:
             return TaskResult(task=task, model=None, train_seconds=0.0, executor_id=eid, error=repr(e))
-        self.wal.record(WALRecord(task_id=task.task_id, key=task.key(), seconds=secs,
-                                  executor_id=eid, score=score,
-                                  convert_seconds=conv, eval_seconds=eval_s))
-        if rstate is not None:
-            self.wal.record_resume(task.task_id, rstate)
-        return TaskResult(task=task, model=model, train_seconds=secs,
-                          executor_id=eid, convert_seconds=conv,
-                          score=score, eval_seconds=eval_s,
-                          resume_state=rstate)
+        self.wal.record(WALRecord(task_id=task.task_id, key=task.key(),
+                                  seconds=res.train_seconds, executor_id=eid,
+                                  score=res.score,
+                                  convert_seconds=res.convert_seconds,
+                                  eval_seconds=res.eval_seconds))
+        if res.resume_state is not None:
+            self.wal.record_resume(task.task_id, res.resume_state)
+        return res
 
     def _run_fused(self, eid: int, unit: FusedBatch, sl, data,
                    validate: EvalPlan | None = None,
@@ -1095,7 +1081,7 @@ class MeshSliceExecutorPool:
             results = _run_fused_unit(sub, data, eid,
                                       cache=self.prepared_cache,
                                       placement=self._placement(sl),
-                                      validate=validate)
+                                      validate=validate, search=self._search)
             for res in results:
                 if res.ok:
                     self.wal.record(WALRecord(
@@ -1245,6 +1231,7 @@ class MeshSliceExecutorPool:
         """
         self._stragglers = []  # per-submit buffer (see drain_stragglers)
         self._pending_retry = []
+        self._search = assignment.search   # the id the unit spans carry
         queues = self._queues(assignment)
         alive = set(range(len(self.slices)))
         stranded: list[TrainTask] = []

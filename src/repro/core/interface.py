@@ -40,6 +40,7 @@ from repro.core.data_format import (
     prepare_key,
     prepared_data_cache,
 )
+from repro.core.tracing import span
 
 __all__ = [
     "Estimator",
@@ -484,9 +485,9 @@ def run_prepared(
         est, raw, params, cache, placement)
     pcache.pin(key)
     try:
-        t0 = time.perf_counter()
-        model = est.train(prepared, dict(params))
-        return model, time.perf_counter() - t0, convert_seconds
+        with span("repro.train", family=est.name, size=1) as sp:
+            model = est.train(prepared, dict(params))
+        return model, sp.seconds, convert_seconds
     finally:
         pcache.unpin(key)
 
@@ -518,10 +519,10 @@ def run_prepared_resumable(
         est, raw, params, cache, placement)
     pcache.pin(key)
     try:
-        t0 = time.perf_counter()
-        model, new_state = est.train_resumable(
-            prepared, dict(params), budget=int(budget), state=state)
-        return model, time.perf_counter() - t0, convert_seconds, new_state
+        with span("repro.train", family=est.name, size=1) as sp:
+            model, new_state = est.train_resumable(
+                prepared, dict(params), budget=int(budget), state=state)
+        return model, sp.seconds, convert_seconds, new_state
     finally:
         pcache.unpin(key)
 
@@ -550,10 +551,12 @@ def run_prepared_batched(
         est, raw, first, cache, placement)
     pcache.pin(key)
     try:
-        t0 = time.perf_counter()
-        models = est.train_batched(prepared, [dict(p) for p in params_list],
-                                   cache=compile_cache)
-        return models, time.perf_counter() - t0, convert_seconds
+        with span("repro.train", family=est.name,
+                  size=len(params_list)) as sp:
+            models = est.train_batched(prepared,
+                                       [dict(p) for p in params_list],
+                                       cache=compile_cache)
+        return models, sp.seconds, convert_seconds
     finally:
         pcache.unpin(key)
 

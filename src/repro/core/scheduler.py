@@ -56,6 +56,9 @@ class Assignment:
     plan: list[list[TrainTask]]
     estimated_loads: list[float]
     policy: str
+    #: the id of the search that submits it (``Session.search_id``); the
+    #: executors put it on their ``repro.unit`` spans
+    search: int = 0
 
     @property
     def n_executors(self) -> int:

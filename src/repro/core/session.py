@@ -49,6 +49,7 @@ observe loop with a REAL lifecycle instead of a single blocking call:
 from __future__ import annotations
 
 import inspect
+import itertools
 import time
 from typing import Callable, Iterator, Mapping
 
@@ -74,6 +75,7 @@ from repro.core.scheduler import (
     schedule,
 )
 from repro.core.spec import SearchSpec
+from repro.core.tracing import span
 
 __all__ = ["Session", "SearchStats"]
 
@@ -85,6 +87,8 @@ _COST_BLIND = ("random", "round_robin")
 #: signal is trusted, and a single round never replans more than this often
 _MIN_REPLAN_WINDOW = 2
 _MAX_REPLANS_PER_ROUND = 8
+#: ids of the sessions this process creates (``Session.search_id``)
+_SEARCH_IDS = itertools.count(1)
 
 
 class SearchStats:
@@ -176,6 +180,8 @@ class Session:
         #: passed as the spec's profiler. Inspectable mid-stream.
         self.cost_model: CostModel | None = None
         self._observer_installed = False
+        #: process-unique id; every span of this search carries it
+        self.search_id = next(_SEARCH_IDS)
 
     # ------------------------------------------------------------------
     @property
@@ -506,7 +512,7 @@ class Session:
                    if tuner.is_dynamic else None)
         killed_ids: set[int] = set()
         try:
-            while True:
+            for rnd in itertools.count():
                 budget_left = (None if spec.max_tasks is None
                                else max(0, spec.max_tasks - len(self._results)))
                 batch = tuner.suggest(budget_left)
@@ -535,26 +541,29 @@ class Session:
                     if not tuner.is_dynamic:
                         break
                     continue
-                # 1. profile (paper §III-C) — the CostModel serves what it
-                # has learned for free, the profiler covers cold tasks
-                if spec.policy in _COST_BLIND:
-                    costed = list(batch)
-                else:
-                    costed = self._cost_batch(batch, train, profiler, cm)
-                # 2. schedule (greedy job-shop / baselines) — with fusion on,
-                # the plan is over fused units; bottleneck batches split at
-                # bucket boundaries (fusion.split_for_balance). Cold format
-                # groups get their one-time conversion charged to their
-                # first unit (§3.3), so LPT stops mis-ranking them.
-                units = (self._fuse(costed, cm, train.n_rows)
-                         if spec.fuse else costed)
-                # §3.4: every unit that will be scored executor-side carries
-                # its eval estimate; §3.3: cold formats' one-time conversion
-                units = self._charge_eval(units, cm, eval_plan)
-                units = self._charge_conversion(units, cm, train)
-                assignment = schedule(
-                    units, spec.n_executors, policy=spec.policy, seed=spec.seed,
-                    splitter=split_for_balance if spec.fuse else None)
+                with span("repro.session.plan", search=self.search_id,
+                          round=rnd) as plan_span:
+                    # 1. profile (paper §III-C) — the CostModel serves what it
+                    # has learned for free, the profiler covers cold tasks
+                    if spec.policy in _COST_BLIND:
+                        costed = list(batch)
+                    else:
+                        costed = self._cost_batch(batch, train, profiler, cm)
+                    # 2. schedule (greedy job-shop / baselines) — with fusion on,
+                    # the plan is over fused units; bottleneck batches split at
+                    # bucket boundaries (fusion.split_for_balance). Cold format
+                    # groups get their one-time conversion charged to their
+                    # first unit (§3.3), so LPT stops mis-ranking them.
+                    units = (self._fuse(costed, cm, train.n_rows)
+                             if spec.fuse else costed)
+                    # §3.4: every unit that will be scored executor-side carries
+                    # its eval estimate; §3.3: cold formats' one-time conversion
+                    units = self._charge_eval(units, cm, eval_plan)
+                    units = self._charge_conversion(units, cm, train)
+                    assignment = schedule(
+                        units, spec.n_executors, policy=spec.policy, seed=spec.seed,
+                        splitter=split_for_balance if spec.fuse else None)
+                    plan_span.set(n_units=len(assignment.all_tasks()))
                 # 3. execute — stream results off the backend as they land.
                 # When observed runtimes drift past spec.replan_threshold,
                 # cancel the stream, re-estimate the remaining tasks from
@@ -603,6 +612,7 @@ class Session:
                         on_result(res)
 
                 while True:
+                    assignment.search = self.search_id
                     stream = (backend.submit(assignment, train,
                                              validate=eval_plan)
                               if eval_plan is not None
@@ -671,24 +681,27 @@ class Session:
                         break
                     # feedback: re-cost the remainder, then rebalance — never
                     # accepting a plan worse than the current residual
-                    pending = self._reestimate(pending, train, cm, round_results)
-                    if spec.fuse:
-                        pending_units = self._pending_units(
-                            assignment, pending, cm, train.n_rows)
-                        pending_units = self._charge_eval(
-                            pending_units, cm, eval_plan)
-                        pending_units = self._charge_conversion(
-                            pending_units, cm, train)
-                        assignment = replan(
-                            pending_units, spec.n_executors,
-                            current=restrict(assignment, pending_units),
-                            policy=spec.policy, splitter=split_for_balance)
-                    else:
-                        pending = self._charge_eval(pending, cm, eval_plan)
-                        pending = self._charge_conversion(pending, cm, train)
-                        assignment = replan(pending, spec.n_executors,
-                                            current=restrict(assignment, pending),
-                                            policy=spec.policy)
+                    with span("repro.session.plan", search=self.search_id,
+                              round=rnd) as plan_span:
+                        pending = self._reestimate(pending, train, cm, round_results)
+                        if spec.fuse:
+                            pending_units = self._pending_units(
+                                assignment, pending, cm, train.n_rows)
+                            pending_units = self._charge_eval(
+                                pending_units, cm, eval_plan)
+                            pending_units = self._charge_conversion(
+                                pending_units, cm, train)
+                            assignment = replan(
+                                pending_units, spec.n_executors,
+                                current=restrict(assignment, pending_units),
+                                policy=spec.policy, splitter=split_for_balance)
+                        else:
+                            pending = self._charge_eval(pending, cm, eval_plan)
+                            pending = self._charge_conversion(pending, cm, train)
+                            assignment = replan(pending, spec.n_executors,
+                                                current=restrict(assignment, pending),
+                                                policy=spec.policy)
+                        plan_span.set(n_units=len(assignment.all_tasks()))
                     replans_left -= 1
                     self.stats.n_replans += 1
                 self.stats.execution_seconds += time.perf_counter() - t0

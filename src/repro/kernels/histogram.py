@@ -405,6 +405,8 @@ def fused_level_split_tpu(
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
+        # the device op's name in a trace, whatever program calls the kernel
+        name="fused_level_split_tpu",
     )(bins_p, node_p, g_p, h_p, lanes, fmask, sil, parent, scal)
 
     def heap(x):

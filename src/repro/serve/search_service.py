@@ -61,7 +61,7 @@ from repro.core.evaluation import EvalPlan, predict_compile_cache
 # units with EXACTLY the pools' semantics (amortized fused accounting,
 # solo scoring, task-level failure isolation) — re-implementing them here
 # would let the two drift apart
-from repro.core.executor import _run_fused_unit, _score_solo, _train_solo
+from repro.core.executor import _run_fused_unit, _run_solo
 from repro.core.fault import (
     ExecutorFailure,
     RetryLedger,
@@ -96,13 +96,14 @@ class _Ticket:
     round of planned units and the shared workers. Counters are mutated
     under the service condition lock only."""
 
-    __slots__ = ("ctx", "data", "validate", "out", "undispatched", "inflight",
-                 "cancelled", "finished", "done")
+    __slots__ = ("ctx", "data", "validate", "search", "out", "undispatched",
+                 "inflight", "cancelled", "finished", "done")
 
-    def __init__(self, ctx: "_SessionCtx", data, validate):
+    def __init__(self, ctx: "_SessionCtx", data, validate, search: int = 0):
         self.ctx = ctx
         self.data = data
         self.validate = validate
+        self.search = search          # the id the unit spans carry
         self.out: _queue.Queue = _queue.Queue()   # TaskResult | _DONE
         self.undispatched = 0
         self.inflight = 0
@@ -160,7 +161,7 @@ class _TenantBackend:
         hit, replan) mirrors pool semantics: undispatched units are
         withdrawn, in-flight units FINISH (they are on shared workers) and
         park as stragglers for ``drain_stragglers``."""
-        ticket = _Ticket(self._ctx, data, validate)
+        ticket = _Ticket(self._ctx, data, validate, assignment.search)
         units = sorted(assignment.all_tasks(),
                        key=lambda t: -(getattr(t, "cost", None) or 0.0))
         self._service._enqueue(ticket, [_Unit(ticket, t) for t in units])
@@ -754,7 +755,8 @@ class SearchService:
                 results = _run_fused_unit(sub, ticket.data, wid,
                                           cache=self.prepared_cache,
                                           placement=ticket.ctx.backend.placement,
-                                          validate=ticket.validate)
+                                          validate=ticket.validate,
+                                          search=ticket.search)
         else:
             if wal.is_done(task.task_id):
                 return []
@@ -768,19 +770,12 @@ class SearchService:
             try:
                 if self.failure_hook is not None:
                     self.failure_hook(wid, task)  # may raise ExecutorFailure
-                # _train_solo dispatches RungTasks through the resumable
+                # _run_solo dispatches RungTasks through the resumable
                 # path (§3.6), so adaptive tenants get warm rungs too
-                est, model, secs, conv, rstate = _train_solo(
-                    task, ticket.data, cache=self.prepared_cache,
-                    placement=ticket.ctx.backend.placement)
-                score, eval_s = _score_solo(est, model, ticket.validate,
-                                            self.prepared_cache,
-                                            placement=ticket.ctx.backend.placement)
-                results = [TaskResult(task=task, model=model,
-                                      train_seconds=secs, executor_id=wid,
-                                      convert_seconds=conv, score=score,
-                                      eval_seconds=eval_s,
-                                      resume_state=rstate)]
+                results = [_run_solo(task, ticket.data, wid, ticket.validate,
+                                     cache=self.prepared_cache,
+                                     placement=ticket.ctx.backend.placement,
+                                     search=ticket.search)]
             except ExecutorFailure:
                 raise
             except Exception as e:     # task-level failure, worker survives

@@ -90,13 +90,14 @@ def build_tree(
     for level in range(max_depth):
         n_nodes = 1 << level
         keep_hist = subtract and level + 1 < max_depth
-        parent, best_gain, feat, split = ops.level_split(
-            bins, g, h, node, n_nodes=n_nodes, n_bins=n_bins,
-            lam=lam, min_child_weight=min_child_weight,
-            bin_limit=bin_limit, feat_mask=feat_mask,
-            parent_hist=parent if subtract else None,
-            return_hist=keep_hist, force=force,
-            axis_name=axis_name, row_valid=row_valid)
+        with jax.named_scope("level_split"):
+            parent, best_gain, feat, split = ops.level_split(
+                bins, g, h, node, n_nodes=n_nodes, n_bins=n_bins,
+                lam=lam, min_child_weight=min_child_weight,
+                bin_limit=bin_limit, feat_mask=feat_mask,
+                parent_hist=parent if subtract else None,
+                return_hist=keep_hist, force=force,
+                axis_name=axis_name, row_valid=row_valid)
         is_leaf = best_gain <= gamma
         if depth_limit is not None:
             is_leaf = is_leaf | (level >= depth_limit)
@@ -104,18 +105,20 @@ def build_tree(
         split = jnp.where(is_leaf, n_bins - 1, split)    # sentinel: all left
         feats.append(feat)
         splits.append(split)
-        row_bin = jnp.take_along_axis(bins, feat[node][:, None], axis=1)[:, 0]
-        node = 2 * node + (row_bin > split[node]).astype(jnp.int32)
+        with jax.named_scope("row_routing"):
+            row_bin = jnp.take_along_axis(bins, feat[node][:, None], axis=1)[:, 0]
+            node = 2 * node + (row_bin > split[node]).astype(jnp.int32)
     n_leaves = 1 << max_depth
-    if row_valid is not None:
-        g = jnp.where(row_valid, g, 0.0)
-        h = jnp.where(row_valid, h, 0.0)
-    leaf_g = jnp.zeros((n_leaves,), jnp.float32).at[node].add(g)
-    leaf_h = jnp.zeros((n_leaves,), jnp.float32).at[node].add(h)
-    if axis_name is not None:
-        # per-shard leaf sums → global: leaf values become shard-invariant
-        leaf_g = jax.lax.psum(leaf_g, axis_name)
-        leaf_h = jax.lax.psum(leaf_h, axis_name)
+    with jax.named_scope("leaf_sums"):
+        if row_valid is not None:
+            g = jnp.where(row_valid, g, 0.0)
+            h = jnp.where(row_valid, h, 0.0)
+        leaf_g = jnp.zeros((n_leaves,), jnp.float32).at[node].add(g)
+        leaf_h = jnp.zeros((n_leaves,), jnp.float32).at[node].add(h)
+        if axis_name is not None:
+            # per-shard leaf sums → global: leaf values become shard-invariant
+            leaf_g = jax.lax.psum(leaf_g, axis_name)
+            leaf_h = jax.lax.psum(leaf_h, axis_name)
     return jnp.concatenate(feats), jnp.concatenate(splits), leaf_g, leaf_h
 
 
@@ -162,8 +165,9 @@ def predict_raw_margin(x, feat, thresh, leaves, base, *, max_depth: int):
             local = 2 * local + (xv > tt[g]).astype(jnp.int32)
         return margin + tl[local], 0.0
 
-    margin0 = jnp.full((r,), jnp.float32(0.0), jnp.float32) + base
-    margin, _ = jax.lax.scan(one_tree, margin0, (feat, thresh, leaves))
+    with jax.named_scope("tree_routing"):
+        margin0 = jnp.full((r,), jnp.float32(0.0), jnp.float32) + base
+        margin, _ = jax.lax.scan(one_tree, margin0, (feat, thresh, leaves))
     return margin
 
 
@@ -255,9 +259,10 @@ def _fit_gbdt_core(
     cbins = _coarse_bins(bins, factor)
 
     def one_round(margin, r_idx):
-        p = jax.nn.sigmoid(margin)
-        g = p - y
-        h = jnp.maximum(p * (1.0 - p), 1e-16)
+        with jax.named_scope("gradients"):
+            p = jax.nn.sigmoid(margin)
+            g = p - y
+            h = jnp.maximum(p * (1.0 - p), 1e-16)
         feat, split, leaf_g, leaf_h = build_tree(
             cbins, g, h, n_bins=n_bins, max_depth=max_depth,
             lam=lam, gamma=gamma, min_child_weight=min_child_weight,
@@ -265,11 +270,12 @@ def _fit_gbdt_core(
             subtract=subtract, force=force,
             axis_name=axis_name, row_valid=row_valid,
         )
-        # where (not multiply): an empty padded leaf is 0/(0+λ), which for
-        # λ=0 is NaN and would poison the margin through a plain mask
-        leaf_value = jnp.where(
-            r_idx < n_rounds, -eta * leaf_g / (leaf_h + lam), 0.0)
-        margin = margin + predict_margin(cbins, feat, split, leaf_value, max_depth)
+        with jax.named_scope("margin_update"):
+            # where (not multiply): an empty padded leaf is 0/(0+λ), which for
+            # λ=0 is NaN and would poison the margin through a plain mask
+            leaf_value = jnp.where(
+                r_idx < n_rounds, -eta * leaf_g / (leaf_h + lam), 0.0)
+            margin = margin + predict_margin(cbins, feat, split, leaf_value, max_depth)
         return margin, (feat, split, leaf_value)
 
     margin0 = jnp.full((r,), base, jnp.float32)
@@ -298,9 +304,10 @@ def _resume_gbdt_core(
     cbins = _coarse_bins(bins, factor)
 
     def one_round(margin, r_idx):
-        p = jax.nn.sigmoid(margin)
-        g = p - y
-        h = jnp.maximum(p * (1.0 - p), 1e-16)
+        with jax.named_scope("gradients"):
+            p = jax.nn.sigmoid(margin)
+            g = p - y
+            h = jnp.maximum(p * (1.0 - p), 1e-16)
         feat, split, leaf_g, leaf_h = build_tree(
             cbins, g, h, n_bins=n_bins, max_depth=max_depth,
             lam=lam, gamma=gamma, min_child_weight=min_child_weight,
@@ -308,9 +315,10 @@ def _resume_gbdt_core(
             subtract=subtract, force=force,
             axis_name=axis_name, row_valid=row_valid,
         )
-        leaf_value = jnp.where(
-            r_idx < n_rounds, -eta * leaf_g / (leaf_h + lam), 0.0)
-        margin = margin + predict_margin(cbins, feat, split, leaf_value, max_depth)
+        with jax.named_scope("margin_update"):
+            leaf_value = jnp.where(
+                r_idx < n_rounds, -eta * leaf_g / (leaf_h + lam), 0.0)
+            margin = margin + predict_margin(cbins, feat, split, leaf_value, max_depth)
         return margin, (feat, split, leaf_value)
 
     margin, trees = jax.lax.scan(one_round, margin0, start + jnp.arange(rounds))

@@ -9,6 +9,7 @@ process at a time may load the TPU library, and pytest-xdist workers all
 import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +101,20 @@ def test_level_kernel_compiles_for_v5e_under_vmap(one_chip):
     """A fused batch of 4 configs: per-config g/h/node/λ/feature mask over
     shared bins, as ``train_batched`` vmaps ``build_tree``."""
     _compile_level(one_chip, 600_000, 28, 128, 8, True, True, batch=4)
+
+
+def test_level_kernel_names_its_own_device_op(one_chip):
+    """The kernel's custom call is named ``fused_level_split_tpu`` (the name
+    a trace shows, which the benchmark's kernel metrics match) by the kernel
+    itself, not after the program that calls it."""
+    def some_caller(bins, g, h, node, parent, sil, fmask, lam):
+        return fused_level_split_tpu.__wrapped__(
+            bins, g, h, node, n_nodes=8, n_bins=64, lam=lam,
+            min_child_weight=1.0, feat_mask=fmask, parent_hist=parent,
+            small_is_left=sil, return_hist=False)
+
+    text = jax.jit(some_caller).lower(
+        *_level_shapes(one_chip, 4096, 28, 64, 8, True)).compile().as_text()
+    calls = re.findall(r"^\s*%?([\w.\-]+) = .* custom-call\(", text, re.M)
+    assert calls and all(re.match(r"fused_level_split_tpu(\.\d+)?$", c)
+                         for c in calls), calls
