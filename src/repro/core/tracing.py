@@ -31,7 +31,7 @@ import time
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["JOIN_KEYS", "Span", "span"]
+__all__ = ["JOIN_KEYS", "Span", "annotate", "span"]
 
 #: metadata keys a span passes on to the spans opened inside it
 JOIN_KEYS = ("search", "unit")
@@ -42,7 +42,8 @@ _TL = threading.local()
 class Span:
     """One annotated interval; ``seconds`` is set when the block exits."""
 
-    __slots__ = ("name", "meta", "seconds", "_annotation", "_outer", "_t0")
+    __slots__ = ("name", "meta", "seconds", "_annotation", "_outer",
+                 "_outer_span", "_t0")
 
     def __init__(self, name: str, meta: dict):
         self.name = name
@@ -58,6 +59,8 @@ class Span:
         join = {**self._outer,
                 **{k: self.meta[k] for k in JOIN_KEYS if k in self.meta}}
         _TL.join = join
+        self._outer_span = getattr(_TL, "span", None)
+        _TL.span = self
         self._annotation = TraceAnnotation(self.name, **{**join, **self.meta})
         self._annotation.__enter__()
         self._t0 = time.perf_counter()
@@ -67,9 +70,19 @@ class Span:
         self.seconds = time.perf_counter() - self._t0
         self._annotation.__exit__(*exc)
         _TL.join = self._outer
+        _TL.span = self._outer_span
 
 
 def span(name: str, **meta) -> Span:
     """``with span("repro.train", family="gbdt", size=1) as sp: ...`` —
     afterwards ``sp.seconds`` holds the block's wall time."""
     return Span(name, meta)
+
+
+def annotate(**meta) -> None:
+    """Add metadata to the innermost span open on this thread, if any: what
+    only the code inside knows, such as which rows a tree fit's levels read
+    (``level_rows`` on ``repro.train``)."""
+    sp = getattr(_TL, "span", None)
+    if sp is not None:
+        sp.set(**meta)

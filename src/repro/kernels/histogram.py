@@ -31,7 +31,8 @@ One kernel, :func:`fused_level_split_tpu`, is the training hot path: the
 accumulate PLUS the split scan (segmented lane cumsum → gain → masked
 first-max), so only ``(best_gain, best_feat, best_split)`` per node (and,
 when the caller caches parents for histogram subtraction, the level's
-histograms) leave VMEM. Fed the compacted smaller-child rows and the cached
+histograms) leave VMEM. Fed the smaller-child rows (every row with a masked
+node id, or those rows gathered, as ``ops.level_rows`` picks) and the cached
 parent histograms it derives each sibling as ``parent − small`` before the
 scan. :func:`histogram_tpu` is the same kernel returning histograms only.
 
@@ -302,13 +303,17 @@ def fused_level_split_tpu(
 
     Direct mode (``parent_hist=None``): ``node`` holds each row's node in
     ``[0, n_nodes)`` and the kernel accumulates all ``n_nodes`` histograms.
-    Subtraction mode: the caller (``ops.level_split``) has already compacted
-    the rows to the SMALLER child of every sibling pair — ``node`` holds the
-    PARENT id in ``[0, n_nodes/2)`` (pad/invalid rows: ``n_nodes/2``),
-    ``parent_hist`` the cached ``(n_nodes/2, F, B, 2)`` level-above
-    histograms, and ``small_is_left[p]`` whether pair p's smaller child is
-    the left one; the kernel accumulates only the half-size small-child
-    histograms and derives siblings as ``parent − small``.
+    Subtraction mode: ``node`` holds the PARENT id in ``[0, n_nodes/2)`` of
+    each row of the SMALLER child of its sibling pair and the dump id
+    ``n_nodes/2`` on every other row, which lands in a padded accumulator
+    row or matches none and is dropped. The caller (``ops.level_split``)
+    passes every row in place, or only the smaller children's rows gathered
+    into R/2 slots where the one-hot is wide enough for that to pay
+    (``ops.level_rows``); the sums are the same. ``parent_hist`` holds the
+    cached ``(n_nodes/2, F, B, 2)`` level-above histograms and
+    ``small_is_left[p]`` whether pair p's smaller child is the left one; the
+    kernel accumulates only the half-size small-child histograms and
+    derives siblings as ``parent − small``.
 
     ``lam``/``min_child_weight`` may be traced 0-d arrays, ``bin_limit`` a
     traced int — they ride in SMEM. Returns ``(hist | None, best_gain,

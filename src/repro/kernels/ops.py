@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from repro.kernels import ref as _ref
 
 __all__ = ["attention", "decode_attention", "rglru", "rwkv6", "histogram",
-           "level_split"]
+           "level_split", "level_rows"]
 
 
 def _on_tpu() -> bool:
@@ -148,29 +148,71 @@ def _row_cumsum(x, block: int = 1024):
     return (inner + carry[:, None]).reshape(-1)[:n]
 
 
-def _plan_smaller_child(node, n_nodes, n_rows):
+#: one-hot width (features × bins) at or above which a level below the root
+#: gathers its smaller children's rows before the level kernel reads them
+#: (:func:`level_rows`). The kernel's work per row grows with this width, a
+#: row gather's does not. Timed on a v5e, one level by gathered rows against
+#: all rows (ms): 600,000 rows × 28 features at 896–3,584 lanes, 34–37
+#: against 2.4–6.1; fused batches of 16 × 940 rows × 590 features at 18,880
+#: lanes, 1.5–3.7 against 1.4–3.9, at 37,760 and 75,520 lanes, 2.3–7.6
+#: against 2.5–9.0 (PERF.md, "Where the time goes")
+COMPACT_MIN_LANES = 16_384
+
+
+def level_rows(n_features: int, n_bins: int, force=None) -> str:
+    """Which rows a tree level below the root feeds its histogram build
+    under histogram subtraction (DESIGN.md §3.8): ``"half"`` — the smaller
+    children's rows gathered into ``floor(R/2)`` slots — or ``"all"`` —
+    every row where it lies, the larger children's under a dump node id.
+
+    Both forms accumulate the same rows in the same order; they differ in
+    cost. Gathering rows pays where the per-row histogram work is large: in
+    the XLA scatter, whose updates it halves, and in the level kernel at a
+    one-hot width of :data:`COMPACT_MIN_LANES` lanes or more. Narrower, the
+    kernel reads a row faster than the chip gathers one, so it reads them
+    all. Chosen from shapes alone, per fit."""
+    if _use_level_kernel(force, n_bins) and (
+            n_features * n_bins < COMPACT_MIN_LANES):
+        return "all"
+    return "half"
+
+
+def _plan_smaller_child(node, n_nodes, *, compact: bool):
     """Histogram-subtraction plan for one tree level (DESIGN.md §3.8).
 
     ``node``: (R,) CHILD-level assignment in [0, n_nodes). For every sibling
-    pair (2p, 2p+1) pick the child with fewer rows (ties → left), then build
-    a COMPACTED index set covering only smaller-child rows: per-pair minima
-    sum to ≤ floor(R/2), so ``idx`` has exactly floor(R/2) slots — the row
-    sets this level scatters/gathers are statically half-size, which is
-    where the ~2× histogram-phase win on CPU comes from (on TPU the kernel
-    additionally accumulates half the node histograms). Returns
-    ``(small_is_left, idx, valid)``: (N/2,) bool, (R//2,) int32 row indices
-    (stable order), (R//2,) bool marking really-filled slots.
+    pair (2p, 2p+1) pick the child with fewer rows (ties → left): only its
+    rows are accumulated, under their PARENT id ``p``; every other row takes
+    the dump id ``n_nodes // 2``, which the scatter and the kernel drop.
+    Returns ``(small_is_left, idx, snode)`` with ``small_is_left`` (N/2,)
+    bool, in one of two forms (:func:`level_rows` picks):
+
+    * ``compact=False``: rows stay in place — ``idx`` is None and ``snode``
+      (R,) is every row's id;
+    * ``compact=True``: the smaller children's rows are gathered into
+      ``floor(R/2)`` slots (per-pair minima sum to at most R/2) — ``idx``
+      (R//2,) int32 row indices in stable order, ``snode`` (R//2,) their
+      ids, the dump id on unfilled slots. This halves the rows the XLA
+      scatter (or the kernel) reads, at the price of the gathers.
     """
-    cnt = jnp.zeros((n_nodes,), jnp.int32).at[node].add(1)
+    # rows per node as a one-hot reduction: in HIGGS-width fits (600,000
+    # rows) on a v5e the scatter-adds of the counts took 142 ms of device
+    # time a configuration, this reduction with the row masks 44 ms
+    cnt = (node[:, None] == jnp.arange(n_nodes)[None, :]).sum(
+        axis=0, dtype=jnp.int32)
     small_is_left = cnt[0::2] <= cnt[1::2]
     is_small = jnp.stack([small_is_left, ~small_is_left], axis=1).reshape(-1)
     row_small = is_small[node]
+    n_half = n_nodes // 2                    # the dump id
+    if not compact:
+        return small_is_left, None, jnp.where(row_small, node // 2, n_half)
+    n_rows = node.shape[0]
     cap = n_rows // 2
     pos = _row_cumsum(row_small) - 1         # stable slot of each small row
     slot = jnp.where(row_small, pos, cap)    # cap = out of bounds → dropped
     idx = jnp.zeros((cap,), jnp.int32).at[slot].set(jnp.arange(n_rows))
     valid = jnp.arange(cap) < row_small.sum()
-    return small_is_left, idx, valid
+    return small_is_left, idx, jnp.where(valid, node[idx] // 2, n_half)
 
 
 def _sharded_level_split(
@@ -265,39 +307,35 @@ def level_split(
         return (hist if return_hist else None), bg, bf, bs
     use_kernel = _use_level_kernel(force, n_bins)
     subtract = parent_hist is not None and n_nodes > 1
+    sil = None
     if subtract:
-        sil, idx, valid = _plan_smaller_child(node, n_nodes, bins.shape[0])
-        n_half = n_nodes // 2
-        sbins, sg, sh = bins[idx], g[idx], h[idx]
-        snode = jnp.where(valid, node[idx] // 2, n_half)  # n_half = dump slot
-        if use_kernel:
-            from repro.kernels.histogram import fused_level_split_tpu
-
-            # materialize the compacted rows: fused into the kernel's input
-            # padding, the vmapped gathers took the v5e compiler ~25 s per
-            # level of a fused batch, against ~4 s
-            sbins, sg, sh, snode = jax.lax.optimization_barrier(
-                (sbins, sg, sh, snode))
-            return fused_level_split_tpu(
-                sbins, sg, sh, snode, n_nodes=n_nodes, n_bins=n_bins,
-                lam=lam, min_child_weight=min_child_weight,
-                bin_limit=bin_limit, feat_mask=feat_mask,
-                parent_hist=parent_hist, small_is_left=sil,
-                interpret=not _on_tpu(), return_hist=return_hist)
-        small = _histogram_scatter(sbins, sg, sh, snode, n_half, n_bins)
-        big = parent_hist - small
-        silb = sil[:, None, None, None]
-        hist = jnp.stack(
-            [jnp.where(silb, small, big), jnp.where(silb, big, small)], axis=1,
-        ).reshape(n_nodes, bins.shape[1], n_bins, 2)
-    elif use_kernel:
+        sil, idx, node = _plan_smaller_child(
+            node, n_nodes,
+            compact=level_rows(bins.shape[1], n_bins, force) == "half")
+        if idx is not None:
+            bins, g, h = bins[idx], g[idx], h[idx]
+            if use_kernel:
+                # materialize the compacted rows: fused into the kernel's
+                # input padding, the vmapped gathers took the v5e compiler
+                # ~25 s per level of a fused batch, against ~4 s
+                bins, g, h, node = jax.lax.optimization_barrier(
+                    (bins, g, h, node))
+    if use_kernel:
         from repro.kernels.histogram import fused_level_split_tpu
 
         return fused_level_split_tpu(
             bins, g, h, node, n_nodes=n_nodes, n_bins=n_bins,
             lam=lam, min_child_weight=min_child_weight, bin_limit=bin_limit,
-            feat_mask=feat_mask, interpret=not _on_tpu(),
+            feat_mask=feat_mask, parent_hist=parent_hist if subtract else None,
+            small_is_left=sil, interpret=not _on_tpu(),
             return_hist=return_hist)
+    if subtract:
+        small = _histogram_scatter(bins, g, h, node, n_nodes // 2, n_bins)
+        big = parent_hist - small
+        silb = sil[:, None, None, None]
+        hist = jnp.stack(
+            [jnp.where(silb, small, big), jnp.where(silb, big, small)], axis=1,
+        ).reshape(n_nodes, bins.shape[1], n_bins, 2)
     else:
         hist = _histogram_scatter(bins, g, h, node, n_nodes, n_bins)
     bg, bf, bs = _ref.split_scan_ref(

@@ -23,7 +23,7 @@ from repro.core.interface import (
     TrainedModel,
     register_estimator,
 )
-from repro.tabular.gbdt import batched_tree_margins, build_tree
+from repro.tabular.gbdt import batched_tree_margins, build_tree, note_level_rows
 
 __all__ = ["ForestEstimator", "ForestModel"]
 
@@ -288,6 +288,7 @@ class ForestEstimator(Estimator):
         n_bins = int(data["n_bins"])
         f = bins.shape[-1]
         max_depth = int(p["max_depth"])
+        note_level_rows(data, n_bins)
         if is_sharded_payload(data):
             feat, split, leaves = _fit_forest_sharded(
                 bins, data["y"], data["_shard_valid"],
@@ -315,6 +316,7 @@ class ForestEstimator(Estimator):
         bins, edges = data["bins"], data["edges"]
         f = bins.shape[-1]
         max_depth = int(p["max_depth"])
+        note_level_rows(data, int(data["n_bins"]))
         target = int(budget)
         if state is None:
             start = 0
@@ -378,6 +380,7 @@ class ForestEstimator(Estimator):
         max_features = max(1, int(np.sqrt(f)))
         pad_trees = fusion.pad_pow2(max(int(p["n_estimators"]) for p in ps))
         pad_depth = max(int(p["max_depth"]) for p in ps)
+        note_level_rows(data, n_bins)
         cc = cache if cache is not None else fusion.compile_cache()
         if is_sharded_payload(data):
             n_rows, n_shards = int(data["_n_rows"]), int(data["_n_shards"])

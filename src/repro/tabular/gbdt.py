@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.data_format import is_sharded_payload
 from repro.core.evaluation import predict_compile_cache, stable_sigmoid
 from repro.core.interface import (
@@ -224,6 +225,15 @@ def batched_tree_margins(models, x, *, cache=None) -> np.ndarray:
                      jnp.asarray(leaves), base)
         out[idxs] = np.asarray(margins)
     return out
+
+
+def note_level_rows(data, n_bins: int) -> None:
+    """Name on the open ``repro.train`` span which rows the fit's levels
+    below the root read: ``ops.level_rows`` of its width, or ``"all"`` for
+    row-sharded data, whose levels mask rows in place (DESIGN.md §3.9)."""
+    rows = ("all" if is_sharded_payload(data)
+            else ops.level_rows(int(data["bins"].shape[-1]), n_bins))
+    tracing.annotate(level_rows=rows)
 
 
 def _coarse_bins(bins, factor):
@@ -529,6 +539,7 @@ class GBDTEstimator(Estimator):
         bins, edges, y = data["bins"], data["edges"], data["y"]
         factor, n_cbins = self._coarsen(int(data["n_bins"]), int(p["max_bin"]))
         max_depth, rounds = int(p["max_depth"]), int(p["round"])
+        note_level_rows(data, n_cbins)
         if is_sharded_payload(data):
             base = self._sharded_base_margin(data)
             feat, split, leaves = _fit_gbdt_sharded(
@@ -561,6 +572,7 @@ class GBDTEstimator(Estimator):
         bins, edges, y = data["bins"], data["edges"], data["y"]
         factor, n_cbins = self._coarsen(int(data["n_bins"]), int(p["max_bin"]))
         max_depth = int(p["max_depth"])
+        note_level_rows(data, n_cbins)
         sharded = is_sharded_payload(data)
         base = self._sharded_base_margin(data) if sharded else self._base_margin(y)
         target = int(budget)
@@ -640,6 +652,7 @@ class GBDTEstimator(Estimator):
         pad_bins = max(nc for _, nc in coarse)
         pad_rounds = fusion.pad_pow2(max(int(p["round"]) for p in ps))
         pad_depth = max(int(p["max_depth"]) for p in ps)
+        note_level_rows(data, pad_bins)
         cc = cache if cache is not None else fusion.compile_cache()
         if is_sharded_payload(data):
             n_shards = int(data["_n_shards"])

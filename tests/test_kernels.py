@@ -1,11 +1,14 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracle,
 sweeping shapes and dtypes (the tests/ contract for kernels/)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.histogram import fused_level_split_tpu
 
 
 def _rand(rng, shape, dtype):
@@ -306,6 +309,81 @@ def test_level_split_kernel_vs_ref(rng, r, f, nb, nn):
         np.testing.assert_allclose(np.asarray(out[0]), np.asarray(hr),
                                    atol=1e-4)
         _assert_decisions(hr, bgr, out, **scan)
+
+
+def _compacted_kernel(bins, g, h, node, parent, nn, **kw):
+    """A subtraction level in the compacted form, as the kernel branch of
+    ``ops.level_split`` runs it where ``ops.level_rows`` says "half": the
+    smaller children's rows gathered into R/2 slots."""
+    sil, idx, snode = ops._plan_smaller_child(node, nn, compact=True)
+    return fused_level_split_tpu(bins[idx], g[idx], h[idx], snode,
+                                 n_nodes=nn, parent_hist=parent,
+                                 small_is_left=sil, interpret=True, **kw)
+
+
+# narrow levels, where the kernel reads every row with a masked node id
+@pytest.mark.parametrize("f,nb,nn", [
+    (f, nb, nn) for f in (3, 28) for nb in (16, 64, 128) for nn in (2, 8, 32)])
+def test_level_split_masked_subtraction_vs_ref_and_compacted(rng, f, nb, nn):
+    assert ops.level_rows(f, nb, force="kernel") == "all"
+    bins, g, h, node = _level_fixture(rng, 300, f, nb, nn)
+    scan = dict(n_bins=nb, lam=1.0, min_child_weight=1.0)
+    hr, bgr, _, _ = ops.level_split(bins, g, h, node, n_nodes=nn,
+                                    force="ref", **scan)
+    parent = _parent_of(bins, g, h, node, nn, nb)
+    masked = ops.level_split(bins, g, h, node, n_nodes=nn, parent_hist=parent,
+                             force="kernel", **scan)
+    compacted = _compacted_kernel(bins, g, h, node, parent, nn, **scan)
+    for out in (masked, compacted):
+        np.testing.assert_allclose(np.asarray(out[0]), np.asarray(hr),
+                                   atol=1e-4)
+        _assert_decisions(hr, bgr, out, **scan)
+    # the same rows in the same order, in other row blocks
+    np.testing.assert_allclose(np.asarray(masked[0]),
+                               np.asarray(compacted[0]), atol=1e-5)
+
+
+def test_level_split_masked_subtraction_limits_and_mask(rng):
+    """The masked form under a traced bin limit and a forest feature mask,
+    with and without the histogram output: the same decisions as the
+    compacted form's, within the near-tie contract of the oracle's."""
+    f, nb, nn = 28, 64, 8
+    bins, g, h, node = _level_fixture(rng, 400, f, nb, nn)
+    mask = jnp.asarray(np.arange(f) % 3 == 0)
+    scan = dict(n_bins=nb, lam=0.5, min_child_weight=1.0, feat_mask=mask)
+    parent = _parent_of(bins, g, h, node, nn, nb)
+
+    @functools.partial(jax.jit, static_argnames="return_hist")
+    def masked(blim, return_hist):
+        return ops.level_split(bins, g, h, node, n_nodes=nn, bin_limit=blim,
+                               parent_hist=parent, return_hist=return_hist,
+                               force="kernel", **scan)
+
+    blim = jnp.int32(16)
+    full, slim = masked(blim, True), masked(blim, False)
+    assert slim[0] is None
+    for a, b in zip(full[1:], slim[1:]):
+        assert bool((np.asarray(a) == np.asarray(b)).all())
+    hr = ref.histogram_ref(bins, g, h, node, nn, nb)
+    bgr = ops.level_split(bins, g, h, node, n_nodes=nn, bin_limit=16,
+                          force="ref", **scan)[1]
+    compacted = _compacted_kernel(bins, g, h, node, parent, nn, bin_limit=blim,
+                                  return_hist=False, **scan)
+    for out in (slim, compacted):
+        _assert_decisions(hr, bgr, out, bin_limit=16, **scan)
+        real = np.isfinite(np.asarray(out[1]))
+        assert bool(np.asarray(mask)[np.asarray(out[2])[real]].all())
+        assert bool((np.asarray(out[3]) < 15).all())
+
+
+@pytest.mark.parametrize("nb", [32, 64, 128])
+def test_level_rows_rule_separates_higgs_from_secom(nb):
+    """The kernel reads every row at HIGGS width (28 features) and the
+    smaller children's, gathered, at SECOM width (590); the XLA scatter
+    (past the kernel's 256 bins) always gathers."""
+    assert ops.level_rows(28, nb, force="kernel") == "all"
+    assert ops.level_rows(590, nb, force="kernel") == "half"
+    assert ops.level_rows(28, 512) == "half"
 
 
 def test_level_split_traced_bin_limit(rng):

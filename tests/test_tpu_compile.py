@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.histogram import fused_level_split_tpu
 
 #: HBM of one v5e chip
@@ -101,6 +102,28 @@ def test_level_kernel_compiles_for_v5e_under_vmap(one_chip):
     """A fused batch of 4 configs: per-config g/h/node/λ/feature mask over
     shared bins, as ``train_batched`` vmaps ``build_tree``."""
     _compile_level(one_chip, 600_000, 28, 128, 8, True, True, batch=4)
+
+
+@pytest.mark.parametrize("b,n_nodes,return_hist", [
+    (b, n, rh) for b in (32, 128) for n in (2, 32) for rh in (True, False)])
+def test_masked_level_compiles_for_v5e(one_chip, b, n_nodes, return_hist):
+    """A level below the root as ``ops.level_split`` runs it at HIGGS width
+    on the chip: every one of 600,000 rows where it lies, the larger
+    children's under the dump id, into the subtraction kernel."""
+    assert ops.level_rows(28, b, force="kernel") == "all"
+
+    def level(bins, g, h, node, parent, _sil, fmask, lam):
+        sil, _, snode = ops._plan_smaller_child(node, n_nodes, compact=False)
+        return fused_level_split_tpu(
+            bins, g, h, snode, n_nodes=n_nodes, n_bins=b, lam=lam,
+            min_child_weight=1.0, feat_mask=fmask, parent_hist=parent,
+            small_is_left=sil, return_hist=return_hist)
+
+    text = jax.jit(level).lower(
+        *_level_shapes(one_chip, 600_000, 28, b, n_nodes, True)).compile(
+        ).as_text()
+    assert "tpu_custom_call" in text
+    assert "[300000" not in text        # no row is gathered into R/2 slots
 
 
 def test_level_kernel_names_its_own_device_op(one_chip):

@@ -11,6 +11,7 @@ import repro.tabular  # noqa: F401  (registers the estimators)
 from bench import trace_reduce
 from repro.core import DenseMatrix, GridBuilder, SearchSpec, Session, tracing
 from repro.core.tracing import span
+from repro.kernels import ops
 
 NAMES = ("repro.session.plan", "repro.unit", "repro.convert", "repro.train",
          "repro.eval", "repro.eval.metric")
@@ -114,9 +115,21 @@ def test_result_seconds_are_the_span_durations(traced):
     assert paid == pytest.approx(builds, abs=1e-3)
 
 
+def test_tree_fits_name_the_rows_their_levels_read(traced):
+    """A GBDT fit's ``repro.train`` span says which rows its levels below
+    the root read (``ops.level_rows`` of the fit's width); a dense fit's
+    span says nothing of it."""
+    spans = traced[3]
+    train = {st["family"]: st for _, name, _, _, st in spans
+             if name == "repro.train"}
+    assert train["gbdt"]["level_rows"] == ops.level_rows(6, 64)
+    assert "level_rows" not in train["logreg"]
+
+
 def test_a_span_passes_its_join_keys_to_the_spans_inside_it():
     with span("repro.unit", search=4, unit=-9, family="gbdt") as outer:
         with span("repro.train", family="gbdt", size=1) as inner:
             assert tracing._TL.join == {"search": 4, "unit": -9}
     assert tracing._TL.join == {}
     assert outer.seconds >= inner.seconds >= 0.0
+    tracing.annotate(level_rows="all")      # no span open: nothing to mark
