@@ -45,7 +45,7 @@ def main(argv=None) -> int:
         return 2
     run.use_compile_cache(jax)
     sys.path.insert(0, drive.src_path())
-    from bench import reference
+    ref, metric = cell.reference(), cell.config["metric"]
 
     out = Path(args.out)
     out.parent.mkdir(parents=True, exist_ok=True)
@@ -58,24 +58,24 @@ def main(argv=None) -> int:
             landed += driver.run_search(i)[0]
         t_search = time.perf_counter() - t0
         failed = [lr for lr in landed if not lr.result.ok]
-        answers = run.answers_of(landed)
+        answers = run.answers_of(landed, ref)
         picked = check.sample(answers, cell.traffic["check_sample"], seed,
                               int(cell.traffic["n_executors"]))
         driver.free()
-        prep = reference.Prepared(*train)
+        prep = ref.Prepared(*train)
         chosen = [answers[i] for i in picked]
         t1 = time.perf_counter()
-        prog = [check.numbers(prep, valid[0], valid[1], a, reference)
+        prog = [check.numbers(prep, valid[0], valid[1], a, ref, metric)
                 for a in chosen]
         t_ref = time.perf_counter() - t1
         ctrl_answers = check.control_answers(prep, valid[0], valid[1], chosen,
-                                             reference)
-        ctrl = [check.numbers(prep, valid[0], valid[1], a, reference)
+                                             ref, metric)
+        ctrl = [check.numbers(prep, valid[0], valid[1], a, ref, metric)
                 for a in ctrl_answers]
         planted = {name: [None if a.family in check.TREES else
                           check.numbers(prep, valid[0], valid[1], plant(a),
-                                        reference) for a in chosen]
-                   for name, plant in _dense_faults(prep, reference).items()}
+                                        ref, metric) for a in chosen]
+                   for name, plant in _dense_faults(prep, ref).items()}
         line = {"seed": seed, "failed": len(failed), "scored": len(answers),
                 "search_s": t_search, "reference_s": t_ref,
                 "program": check.worst(prog), "control": check.worst(ctrl),
@@ -97,18 +97,17 @@ def main(argv=None) -> int:
     return 0
 
 
-def _dense_faults(prep, reference):
+def _dense_faults(prep, ref):
     """Each planted fault as a map from a dense answer to its faulty twin."""
     import jax.numpy as jnp
 
     def half_batch(a):
-        model = reference.fit_dense(prep, a.family, a.params, jnp.float32,
-                                    half=True)
+        model = ref.fit_dense(prep, a.family, a.params, jnp.float32, half=True)
         return dataclasses.replace(a, model=model)
 
     def state_unchanged(a):
         return dataclasses.replace(
-            a, model=reference.dense_init(prep, a.family, a.params))
+            a, model=ref.dense_init(prep, a.family, a.params))
 
     return {"half_batch": half_batch, "state_unchanged": state_unchanged}
 

@@ -4,7 +4,12 @@
 else sits in a file of its own under this directory:
 
 - ``configs/<config>.json``: the dataset deployment, with the generator it
-  names (``generators/<generator>.py``, a ``generate(sizes, seed)``);
+  names (``generators/<generator>.py``, a ``generate(sizes, seed)``) and its
+  task: the ``metric`` the search scores by (a name ``SearchSpec.metric``
+  takes), the model's ``outputs`` per row (1 for binary and regression, K
+  for K classes) and the plain ``reference`` it is checked against
+  (``<reference>.py``, which fulfils the contract at the top of
+  ``reference.py``);
 - ``traffic/<traffic>.json``: the searches, backend, executors and policy;
 - ``metrics/<metric>.py``: one per-layer metric, a ``read(run)`` that
   returns a number or None where it finds nothing to read;
@@ -39,6 +44,9 @@ class Cell:
         return load_module(self.bench_dir / "generators"
                            / f"{self.config['generator']}.py")
 
+    def reference(self) -> ModuleType:
+        return load_module(self.bench_dir / f"{self.config['reference']}.py")
+
     def metric_reader(self, name: str) -> ModuleType:
         return load_module(self.bench_dir / "metrics" / f"{name}.py")
 
@@ -61,6 +69,19 @@ def read_json(path: Path) -> dict:
         return json.load(f)
 
 
+#: what every configuration file states about its task; none has a default
+TASK_KEYS = ("metric", "outputs", "reference")
+
+
+def read_config(path: Path) -> dict:
+    """A configuration file, which has to state its task."""
+    config = read_json(path)
+    missing = [k for k in TASK_KEYS if k not in config]
+    if missing:
+        raise ValueError(f"configuration {path} does not state {missing}")
+    return config
+
+
 def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
@@ -76,7 +97,7 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
     bench_dir = root / Path(configs[w["config"]]["file"]).parent.parent
-    config = read_json(root / configs[w["config"]]["file"])
+    config = read_config(root / configs[w["config"]]["file"])
     traffic = read_json(bench_dir / "traffic" / f"{w['traffic']}.json")
     return Cell(
         name=name, chips=int(w["chips"]), config=config, traffic=traffic,

@@ -1,5 +1,6 @@
 """What decides ``correct``: the window's scored configurations against the
-plain reference (``reference.py``).
+plain reference that the cell's configuration names (its ``"reference"``,
+used only through the contract at the top of ``reference.py``).
 
 After the window has closed, a sample of the configurations it scored,
 drawn from the seed and holding the one that trained longest (and, with
@@ -18,11 +19,12 @@ several executors, one of each executor's), is checked:
 - ``loss_gap`` (MLP, logistic regression): how far the objective the family
   minimises, on every training row, at the program's model lies from its
   value at the reference's model, relative to the latter;
-- ``score_gap`` (GBDT, forest): how far the validation AUC the search
-  reported lies from the reference's AUC of the same model. Tree routing is
-  exact, so the two agree to the last digit. A dense model's score is not
-  compared: its margins round differently in every matmul lowering, which
-  moves the ranks of a small validation set as far as the control does.
+- ``score_gap`` (GBDT, forest): how far the validation score the search
+  reported, by the configuration's ``metric``, lies from the reference's
+  score of the same model by that metric. Tree routing is exact, so an AUC
+  agrees to the last digit. A dense model's score is not compared: its
+  margins round differently in every matmul lowering, which moves the ranks
+  of a small validation set as far as the control does.
 
 Each number is the largest over the sample, and is held to its limit in the
 configuration file (``correct_limits``); a configuration compares only the
@@ -54,21 +56,6 @@ class Answer:
     executor: int = 0
 
 
-def program_model(family: str, model) -> dict | None:
-    """The program's trained model in the reference's plain form."""
-    if model is None:
-        return None
-    if family in TREES:
-        return {"feat": np.asarray(model.feat), "thresh": np.asarray(model.thresh),
-                "leaves": np.asarray(model.leaves),
-                "base": float(getattr(model, "base", 0.0)),
-                "depth": int(model.max_depth)}
-    if family == "mlp":
-        return {"layers": [(np.asarray(w), np.asarray(b)) for w, b in model.params]}
-    return {"layers": [(np.asarray(model.w)[:, None],
-                        np.asarray(model.b, np.float32).reshape(1))]}
-
-
 def sample(answers: Sequence[Answer], per_family: Mapping[str, int],
            seed: int, n_executors: int = 1) -> list[int]:
     """Indices of the answers to check: per family a draw of the given size,
@@ -95,15 +82,18 @@ def _finite(v: float) -> float:
     return float(v) if math.isfinite(v) else UNREADABLE
 
 
-def numbers(prep, x_valid, y_valid, answer: Answer, ref) -> dict[str, float]:
-    """This answer's numbers against the float32 reference."""
+def numbers(prep, x_valid, y_valid, answer: Answer, ref,
+            metric: str) -> dict[str, float]:
+    """This answer's numbers against the float32 reference ``ref``, its
+    score by ``metric``."""
     if answer.model is None or answer.score is None or not math.isfinite(answer.score):
         return {"score_gap": UNREADABLE}
     out: dict[str, float] = {}
     if answer.family in TREES:
         out.update(ref.replay_trees(prep, answer.family, answer.params,
                                     answer.model))
-        got = ref.auc(y_valid, ref.probs(answer.family, answer.model, x_valid))
+        got = ref.score(metric, y_valid,
+                        ref.probs(answer.family, answer.model, x_valid))
         out["score_gap"] = abs(float(answer.score) - got)
     else:
         import jax.numpy as jnp
@@ -118,7 +108,7 @@ def numbers(prep, x_valid, y_valid, answer: Answer, ref) -> dict[str, float]:
 
 
 def control_answers(prep, x_valid, y_valid, answers: Sequence[Answer],
-                    ref) -> list[Answer]:
+                    ref, metric: str) -> list[Answer]:
     """The reference computed in bfloat16 in the program's place: its own
     models of the same configurations, scored at that precision."""
     import jax.numpy as jnp
@@ -126,8 +116,8 @@ def control_answers(prep, x_valid, y_valid, answers: Sequence[Answer],
     out = []
     for a in answers:
         model = ref.fit(prep, a.family, a.params, jnp.bfloat16)
-        score = ref.auc(y_valid, ref.probs(a.family, model, x_valid,
-                                           jnp.bfloat16))
+        score = ref.score(metric, y_valid,
+                          ref.probs(a.family, model, x_valid, jnp.bfloat16))
         out.append(dataclasses.replace(a, model=model, score=score))
     return out
 

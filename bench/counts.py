@@ -9,7 +9,8 @@ of the true utilisation and can never pass 100 %.
 
 A configuration's parameters are the traffic file's, filled in with the
 defaults each family trains with when the traffic leaves them out
-(``DEFAULTS``).
+(``DEFAULTS``). ``outputs`` is the model's outputs per row, as the
+configuration file states it: 1 for binary and regression, K for K classes.
 """
 from __future__ import annotations
 
@@ -28,9 +29,10 @@ DEFAULTS = {
     "logreg": {"c": 1.0, "lr": 0.05, "steps": 200},
 }
 
-#: bytes of one (row, feature) bin id (bins <= 256) and of g and h per row
+#: bytes of one (row, feature) bin id (bins <= 256) and of one float32
+#: statistic of a row (a g, an h or a class count)
 BIN_ID_BYTES = 1
-GH_BYTES_PER_ROW = 8
+STAT_BYTES = 4
 
 
 def params_of(family: str, params: Mapping) -> dict:
@@ -54,54 +56,67 @@ def trees(family: str, params: Mapping) -> tuple[int, int]:
     return 0, 0
 
 
-def histogram_ops(rows: int, features: int, levels: int) -> int:
-    """Histogram adds of one tree: every row adds its g and h into one bin
-    of every feature at every level, 2·R·F per level."""
-    return 2 * rows * features * levels
+def row_stats(family: str, outputs: int) -> int:
+    """Statistics a row adds into a histogram bin, per GBDT round or forest
+    tree: g and h of each output for GBDT (K trees a round), (g, h) of one
+    output or K class counts for the forest."""
+    if family == "gbdt":
+        return 2 * outputs
+    return max(2, outputs)
 
 
-def mlp_parameters(network: str, n_features: int) -> int:
-    dims = [n_features] + [int(h) for h in str(network).split("_")] + [1]
+def histogram_ops(rows: int, features: int, levels: int, stats: int) -> int:
+    """Histogram adds of one GBDT round or forest tree: every row adds its
+    ``stats`` into one bin of every feature at every level, R·F·stats per
+    level."""
+    return stats * rows * features * levels
+
+
+def mlp_parameters(network: str, n_features: int, outputs: int) -> int:
+    dims = [n_features] + [int(h) for h in str(network).split("_")] + [outputs]
     return sum(a * b + b for a, b in zip(dims[:-1], dims[1:]))
 
 
-def config_ops(family: str, params: Mapping, rows: int, features: int) -> int:
+def config_ops(family: str, params: Mapping, rows: int, features: int,
+               outputs: int) -> int:
     """Operations one configuration's training requires.
 
-    GBDT and forest: 2·R·F histogram adds per tree level (a forest tree
-    sees sqrt(F) features). MLP: 6 × parameters × batch rows per step
-    (forward and backward). Logistic regression: 4·R·F per full-batch step.
+    GBDT: 2·R·F histogram adds per tree level, K trees a round. Forest:
+    R·√F·max(2, K) per tree level (a tree sees sqrt(F) features). MLP:
+    6 × parameters × batch rows per step (forward and backward), the last
+    layer K units wide. Logistic regression: 4·R·F·K per full-batch step.
     """
     p = params_of(family, params)
-    if family == "gbdt":
+    if family in ("gbdt", "forest"):
         n, depth = trees(family, p)
-        return n * histogram_ops(rows, features, depth)
-    if family == "forest":
-        n, depth = trees(family, p)
-        return n * histogram_ops(rows, forest_features(features), depth)
+        width = features if family == "gbdt" else forest_features(features)
+        return n * histogram_ops(rows, width, depth, row_stats(family, outputs))
     if family == "mlp":
         batch = min(int(p["batch_size"]), rows)
-        return 6 * mlp_parameters(p["network"], features) * batch * int(p["steps"])
-    return 4 * rows * features * int(p["steps"])
+        return (6 * mlp_parameters(p["network"], features, outputs) * batch
+                * int(p["steps"]))
+    return 4 * rows * features * outputs * int(p["steps"])
 
 
 def level_kernel_bytes(family: str, params: Mapping, rows: int,
-                       features: int) -> int:
+                       features: int, outputs: int) -> int:
     """Least bytes the level work of a configuration's trees must move: per
-    tree one read of every (row, feature) bin id and of g and h. Deeper
-    levels are not counted, because histogram subtraction and row
+    GBDT round or forest tree one read of every (row, feature) bin id and of
+    the row's statistics (``row_stats``: 8·K bytes of g and h for GBDT).
+    Deeper levels are not counted, because histogram subtraction and row
     compaction change which rows a kernel touches there, so this is a
     floor for any implementation."""
     n, _ = trees(family, params)
-    return n * rows * (features * BIN_ID_BYTES + GH_BYTES_PER_ROW)
+    return n * rows * (features * BIN_ID_BYTES
+                       + STAT_BYTES * row_stats(family, outputs))
 
 
 def level_kernel_ops(family: str, params: Mapping, rows: int,
-                     features: int) -> int:
+                     features: int, outputs: int) -> int:
     """Histogram adds of a configuration's trees (0 for dense families)."""
     if family not in ("gbdt", "forest"):
         return 0
-    return config_ops(family, params, rows, features)
+    return config_ops(family, params, rows, features, outputs)
 
 
 def least_seconds(ops: float, nbytes: float, peak: Mapping) -> tuple[float, str]:

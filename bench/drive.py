@@ -137,7 +137,8 @@ class Driver:
         t = self.traffic
         return SearchSpec(spaces=_spaces(search), n_executors=int(t["n_executors"]),
                           policy=t["policy"], profiler=AnalyticProfiler(),
-                          metric="auc", seed=self.seed, fuse=bool(t["fuse"]),
+                          metric=self.cell.config["metric"], seed=self.seed,
+                          fuse=bool(t["fuse"]),
                           max_fuse=int(t["max_fuse"]))
 
     def session(self, search: list[dict]):

@@ -1,8 +1,9 @@
 """The level kernel's share of its roofline, in percent: the least time the
 chip needs for the level work of the trees the traced span trained (the
 larger of their histogram adds over peak FLOP/s and their bytes over peak
-HBM bytes/s, ``counts.py``), over the kernel's device time there. The bytes
-count one read of the bin ids and of g and h per tree, a floor for any
+HBM bytes/s, ``counts.py``, for the configuration's outputs per row), over
+the kernel's device time there. The bytes count one read of the bin ids
+and of the rows' statistics per GBDT round or forest tree, a floor for any
 implementation, so this share is a lower bound."""
 
 from bench import counts
@@ -18,10 +19,10 @@ def read(run):
     done = [r for r in run.scored() if r.task.estimator in ("gbdt", "forest")]
     if kernel <= 0 or not done:
         return None
-    ops = sum(counts.level_kernel_ops(r.task.estimator, r.task.params,
-                                      run.train_rows, run.features) for r in done)
+    shape = (run.train_rows, run.features, run.outputs)
+    ops = sum(counts.level_kernel_ops(r.task.estimator, r.task.params, *shape)
+              for r in done)
     nbytes = sum(counts.level_kernel_bytes(r.task.estimator, r.task.params,
-                                           run.train_rows, run.features)
-                 for r in done)
+                                           *shape) for r in done)
     least, _bound = counts.least_seconds(ops, nbytes, run.peak)
     return 100.0 * least / kernel

@@ -26,6 +26,7 @@ class RunRecord:
     setup: dict                  # convert_s, compile_s of the set-up
     train_rows: int
     features: int
+    outputs: int                 # the model's outputs per row (configuration)
     peak: dict | None            # the chip's row of peaks.json
     trace: trace_reduce.Trace | None = None
     #: the traced span [lo, hi) in the trace's clock, and the devices in it

@@ -1,4 +1,27 @@
-"""Plain reference of the four families the search trains, and the score.
+"""Plain reference of the four families the search trains, and the score,
+for a binary task: one output per row, the logistic loss.
+
+A configuration names the reference it is checked against (its
+``"reference"`` key, a file beside this one). ``check.py``, ``run.py`` and
+``calibrate.py`` use a reference through this contract alone, and a
+reference for another task (more classes, regression) fulfils it in a file
+of its own:
+
+- ``Prepared(x, y)``: the training rows as the reference holds them;
+- ``program_model(family, model)``: the program's trained model in the
+  reference's plain form;
+- ``probs(family, model, x, dtype)``: the model's predictions, in the form
+  the program scores them;
+- ``score(metric, y, probs)``: the configuration's metric, computed as the
+  program reports it;
+- ``replay_trees(prep, family, params, model)``: ``split_gap`` and
+  ``leaf_gap`` of a given GBDT or forest model;
+- ``fit(prep, family, params, dtype)``: the reference's own model;
+- ``fit_dense(prep, family, params, dtype, half=False)``,
+  ``dense_init(prep, family, params)``, ``change_gap(model, want, init)``
+  and ``dense_loss(prep, family, params, model)``: a dense family's fit,
+  the state it starts from, the gap of two changes from that state, and the
+  objective it minimises.
 
 Written from the algorithms' definitions, in straightforward numpy and
 ``jax.numpy``, importing nothing of the program:
@@ -19,7 +42,9 @@ Written from the algorithms' definitions, in straightforward numpy and
   minibatch rows drawn with ``randint`` from a key split once per step;
 - logistic regression: full-batch Adam on the mean logistic loss plus
   ``|w|² / (2·c·n)``;
-- AUC: the Mann-Whitney statistic with average ranks for ties.
+- scores, as ``core/results.py`` defines them: AUC, the Mann-Whitney
+  statistic with average ranks for ties; accuracy at the 0.5 threshold; the
+  negated log loss with probabilities clipped to [1e-7, 1 - 1e-7].
 
 The random draws (initial weights, minibatches, bootstraps, feature subsets)
 are part of each algorithm's definition, so they follow the same
@@ -90,6 +115,29 @@ def auc(y: np.ndarray, s: np.ndarray) -> float:
     avg_rank = ends - (counts - 1) / 2.0
     r_pos = avg_rank[inv][y].sum()
     return float((r_pos - n_pos * (n_pos + 1) / 2.0) / (n_pos * n_neg))
+
+
+def accuracy(y: np.ndarray, s: np.ndarray) -> float:
+    return float(((s >= 0.5) == (np.asarray(y) >= 0.5)).mean())
+
+
+def logloss(y: np.ndarray, s: np.ndarray) -> float:
+    p = np.clip(np.asarray(s, dtype=np.float64), 1e-7, 1 - 1e-7)
+    y = np.asarray(y, dtype=np.float64)
+    return float(-(y * np.log(p) + (1 - y) * np.log(1 - p)).mean())
+
+
+#: the metrics a binary search may be scored by, under the program's names
+SCORES = {"auc": auc, "accuracy": accuracy,
+          "neg_logloss": lambda y, s: -logloss(y, s)}
+
+
+def score(metric: str, y: np.ndarray, probs: np.ndarray) -> float:
+    """The configuration's metric of predicted probabilities ``probs``."""
+    if metric not in SCORES:
+        raise ValueError(f"the binary reference has no metric {metric!r}; "
+                         f"known: {sorted(SCORES)}")
+    return float(SCORES[metric](y, np.asarray(probs)))
 
 
 # ---------------------------------------------------------------------------
@@ -596,6 +644,25 @@ def change_gap(model: dict, want: dict, init: dict) -> float:
 
 
 TREES = ("gbdt", "forest")
+
+
+def program_model(family: str, model) -> dict | None:
+    """The program's trained model in the reference's plain form.
+
+    Binary assumptions of this reference: a tree model has one base margin
+    (``float(model.base)``) and one tree per round, and logistic
+    regression's weights are one column (``model.w[:, None]``)."""
+    if model is None:
+        return None
+    if family in TREES:
+        return {"feat": np.asarray(model.feat), "thresh": np.asarray(model.thresh),
+                "leaves": np.asarray(model.leaves),
+                "base": float(getattr(model, "base", 0.0)),
+                "depth": int(model.max_depth)}
+    if family == "mlp":
+        return {"layers": [(np.asarray(w), np.asarray(b)) for w, b in model.params]}
+    return {"layers": [(np.asarray(model.w)[:, None],
+                        np.asarray(model.b, np.float32).reshape(1))]}
 
 
 def fit(prep: Prepared, family: str, params: Mapping, dtype) -> dict:

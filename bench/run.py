@@ -76,11 +76,12 @@ def memory_peak(devices) -> int:
     return max(peaks) if peaks else 0
 
 
-def answers_of(landed) -> list[check.Answer]:
+def answers_of(landed, ref) -> list[check.Answer]:
+    """The window's trained answers, their models in ``ref``'s form."""
     return [check.Answer(family=lr.result.task.estimator,
                          params=dict(lr.result.task.params),
-                         model=check.program_model(lr.result.task.estimator,
-                                                   lr.result.model),
+                         model=ref.program_model(lr.result.task.estimator,
+                                                 lr.result.model),
                          score=lr.result.score,
                          train_seconds=lr.result.train_seconds,
                          executor=lr.result.executor_id)
@@ -88,14 +89,14 @@ def answers_of(landed) -> list[check.Answer]:
 
 
 def correctness(cell, seed, train, valid, landed, failed):
-    """(correct, checks) of the window's answers against the reference."""
-    from bench import reference
-
-    answers = answers_of(landed)
+    """(correct, checks) of the window's answers against the reference
+    that the cell's configuration names, scored by its metric."""
+    ref, metric = cell.reference(), cell.config["metric"]
+    answers = answers_of(landed, ref)
     picked = check.sample(answers, cell.traffic["check_sample"], seed,
                           int(cell.traffic["n_executors"]))
-    prep = reference.Prepared(*train)
-    per = [check.numbers(prep, valid[0], valid[1], answers[i], reference)
+    prep = ref.Prepared(*train)
+    per = [check.numbers(prep, valid[0], valid[1], answers[i], ref, metric)
            for i in picked]
     ok, checks = check.judge(check.worst(per), cell.config["correct_limits"])
     log(f"checked {len(picked)} of {len(answers)} scored configurations")
@@ -148,6 +149,8 @@ def main(argv=None, require_chip: bool = True) -> int:
     monitor.phase = "check"
     log(f"window: {monitor.programs('window')} programs obtained, "
         f"{monitor.compiled('window')} compiled inside the window")
+    log("window: seconds of each whole search: " + " ".join(
+        f"{b - a:.3f}" for a, b in zip([t0] + ends, ends)))
     peak_bytes = memory_peak(devices)
 
     failed = sum(1 for lr in landed
@@ -159,6 +162,7 @@ def main(argv=None, require_chip: bool = True) -> int:
         setup={"convert_s": convert_s,
                "compile_s": monitor.compile_seconds("setup")},
         train_rows=train[0].shape[0], features=train[0].shape[1],
+        outputs=int(cell.config["outputs"]),
         peak=cells.peaks_for(devices[0].device_kind) if require_chip else None)
     breakdown = None
     if args.trace:

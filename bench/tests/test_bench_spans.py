@@ -28,7 +28,8 @@ def record(devices, host, n_scored=4):
     landed = [types.SimpleNamespace(result=res, search=0)] * n_scored
     rec = RunRecord(cell=types.SimpleNamespace(name="t"), landed=landed,
                     t0=0.0, ends=[1.0], n_executors=1, chips=len(devices),
-                    setup={}, train_rows=1, features=1, peak=None)
+                    setup={}, train_rows=1, features=1, outputs=1,
+                    peak=None)
     rec.attach_trace(tr.Trace(devices=devices, host=host),
                      [types.SimpleNamespace(id=i) for i in range(len(devices))])
     return rec

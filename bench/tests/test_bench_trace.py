@@ -84,7 +84,7 @@ def _record(trace):
     rec = RunRecord(cell=cell, landed=landed, t0=0.0, ends=[1.5],
                     n_executors=1, chips=1,
                     setup={"convert_s": 1.0, "compile_s": 2.0},
-                    train_rows=600_000, features=28,
+                    train_rows=600_000, features=28, outputs=1,
                     peak=cells.peaks_for("TPU v5 lite"))
     rec.attach_trace(trace, [types.SimpleNamespace(id=0)])
     return rec
