@@ -211,21 +211,27 @@ class CostModel:
         self._n_observed = 0
 
     @staticmethod
-    def _family_key(family: str, batched: bool, n_shards: int = 1) -> str:
+    def _family_key(family: str, batched: bool, n_shards: int = 1,
+                    n_classes: int = 0) -> str:
         """Batched (fused) execution gets its OWN family: amortized per-task
         seconds inside a vmap batch follow a different law than solo runs
         (compile amortized away, device kept busy), so the two populations
         must not pollute each other's regression. Sharded execution (§3.9)
         likewise gets a ``#s{n}`` suffix per shard count — its per-step
         psum overhead shifts the law's intercept — and those populations
-        regress on rows-per-shard (:func:`_shard_rows`)."""
+        regress on rows-per-shard (:func:`_shard_rows`). A K-class task
+        (``n_classes = K``, K outputs a row: GBDT grows K trees a round) is
+        a ``#k{K}`` population of its own, so binary and K-class laws never
+        mix."""
         key = f"{family}#batched" if batched else family
-        return f"{key}#s{int(n_shards)}" if n_shards > 1 else key
+        key = f"{key}#s{int(n_shards)}" if n_shards > 1 else key
+        return f"{key}#k{int(n_classes)}" if n_classes else key
 
     # -- write side --------------------------------------------------------
     def observe(self, task: TrainTask, seconds: float, n_rows: int,
                 *, batched: bool = False, n_shards: int = 1,
-                ratio_seconds: float | None = None) -> None:
+                ratio_seconds: float | None = None,
+                n_classes: int = 0) -> None:
         """Record one completed task. No-ops on junk (failed tasks report 0s).
 
         ``batched=True`` records under the family's fused-execution law;
@@ -244,7 +250,7 @@ class CostModel:
         """
         if seconds <= 0 or n_rows <= 0:
             return
-        key = self._family_key(task.estimator, batched, n_shards)
+        key = self._family_key(task.estimator, batched, n_shards, n_classes)
         x, y = math.log(_shard_rows(n_rows, n_shards)), math.log(seconds)
         with self._lock:
             fam = self._buckets.setdefault(key, {})
@@ -258,7 +264,8 @@ class CostModel:
         if self.prior is not None:      # write-through, outside our lock
             self.prior.observe(task, seconds, n_rows, batched=batched,
                                n_shards=n_shards,
-                               ratio_seconds=ratio_seconds)
+                               ratio_seconds=ratio_seconds,
+                               n_classes=n_classes)
 
     def observe_convert(self, fmt_key: str, seconds: float, n_rows: int) -> None:
         """Record one actual uniform→native conversion (a prepared-data
@@ -287,7 +294,8 @@ class CostModel:
         return None
 
     def observe_eval(self, task: "TrainTask | str", seconds: float,
-                     n_rows: int, *, n_shards: int = 1) -> None:
+                     n_rows: int, *, n_shards: int = 1,
+                     n_classes: int = 0) -> None:
         """Record one executor-side scoring (§3.4; ``n_rows`` = EVAL split
         rows — a different axis than the training laws'). Pass the
         TrainTask for bucket resolution; a bare family string feeds only
@@ -300,7 +308,7 @@ class CostModel:
             family, bucket = task, None
         else:
             family, bucket = task.estimator, param_bucket(task.params)
-        family = self._family_key(family, False, n_shards)
+        family = self._family_key(family, False, n_shards, n_classes)
         x, y = math.log(_shard_rows(n_rows, n_shards)), math.log(seconds)
         with self._lock:
             if bucket is not None:
@@ -308,10 +316,11 @@ class CostModel:
                     bucket, _LogStats()).add(x, y)
             self._evals.setdefault(family, _LogStats()).add(x, y)
         if self.prior is not None:
-            self.prior.observe_eval(task, seconds, n_rows, n_shards=n_shards)
+            self.prior.observe_eval(task, seconds, n_rows, n_shards=n_shards,
+                                    n_classes=n_classes)
 
     def predict_eval(self, task: "TrainTask | str", n_rows: int,
-                     *, n_shards: int = 1) -> float | None:
+                     *, n_shards: int = 1, n_classes: int = 0) -> float | None:
         """Per-task eval-seconds estimate at an eval-split size, or None
         before the family has ever been observed scoring. Resolution
         mirrors the training law: exact (family, bucket) stats when a
@@ -324,7 +333,7 @@ class CostModel:
             family, bucket = task, None
         else:
             family, bucket = task.estimator, param_bucket(task.params)
-        family = self._family_key(family, False, n_shards)
+        family = self._family_key(family, False, n_shards, n_classes)
         x = math.log(_shard_rows(n_rows, n_shards))
         with self._lock:
             if bucket is not None:
@@ -335,15 +344,16 @@ class CostModel:
             if stats is not None and stats.n:
                 return math.exp(stats.predict(x, self.default_exponent))
         if self.prior is not None:
-            got = self.prior.predict_eval(task, n_rows, n_shards=n_shards)
+            got = self.prior.predict_eval(task, n_rows, n_shards=n_shards,
+                                          n_classes=n_classes)
             if got is not None:
                 return got
         if n_shards > 1:
-            return self.predict_eval(task, n_rows)
+            return self.predict_eval(task, n_rows, n_classes=n_classes)
         return None
 
     def observe_result(self, result, n_rows: int, eval_rows: int = 0,
-                       *, n_shards: int = 1) -> None:
+                       *, n_shards: int = 1, n_classes: int = 0) -> None:
         """``on_result``-shaped adapter: feed a TaskResult straight in. Fused
         results carry ``batch_size > 1`` and amortized seconds, and land in
         the batched law automatically. A result that BUILT a prepared-data
@@ -363,17 +373,18 @@ class CostModel:
                     and result.train_seconds > 0):
                 self.observe(result.task, result.train_seconds, n_rows,
                              batched=getattr(result, "batch_size", 1) > 1,
-                             n_shards=n_shards)
+                             n_shards=n_shards, n_classes=n_classes)
             return
         batch_size = getattr(result, "batch_size", 1)
         conv = getattr(result, "convert_seconds", 0.0)
         eval_s = getattr(result, "eval_seconds", 0.0)
         self.observe(result.task, result.train_seconds, n_rows,
                      batched=batch_size > 1, n_shards=n_shards,
-                     ratio_seconds=result.train_seconds + conv + eval_s)
+                     ratio_seconds=result.train_seconds + conv + eval_s,
+                     n_classes=n_classes)
         if eval_s > 0 and eval_rows > 0:
             self.observe_eval(result.task, eval_s, eval_rows,
-                              n_shards=n_shards)
+                              n_shards=n_shards, n_classes=n_classes)
         if conv > 0:
             from repro.core.interface import format_law_key, get_estimator
 
@@ -402,7 +413,8 @@ class CostModel:
         return num / den if den else self.default_exponent
 
     def predict(self, task: TrainTask, n_rows: int,
-                *, batched: bool = False, n_shards: int = 1) -> float | None:
+                *, batched: bool = False, n_shards: int = 1,
+                n_classes: int = 0) -> float | None:
         """Size-law prediction in seconds, or None with no relevant data.
 
         Resolution order: exact (family, bucket) stats, then pooled family
@@ -414,7 +426,7 @@ class CostModel:
         """
         if n_rows <= 0:
             return None
-        key = self._family_key(task.estimator, batched, n_shards)
+        key = self._family_key(task.estimator, batched, n_shards, n_classes)
         x = math.log(_shard_rows(n_rows, n_shards))
         with self._lock:
             fam = self._buckets.get(key, {})
@@ -426,11 +438,12 @@ class CostModel:
                 return math.exp(pooled.predict(x, self._family_exponent(key)))
         if self.prior is not None:
             return self.prior.predict(task, n_rows, batched=batched,
-                                      n_shards=n_shards)
+                                      n_shards=n_shards, n_classes=n_classes)
         return None
 
     def estimate(self, task: TrainTask, n_rows: int,
-                 *, batched: bool = False, n_shards: int = 1) -> float | None:
+                 *, batched: bool = False, n_shards: int = 1,
+                 n_classes: int = 0) -> float | None:
         """Best cost estimate for scheduling: bucket law, else the task's own
         prior estimate corrected by the family's observed/estimated ratio,
         else the pooled family law. Still monotone in ``n_rows`` (the ratio
@@ -444,7 +457,7 @@ class CostModel:
         cold SHARDED law (§3.9) falls back the same way: the unsharded
         estimate answers until the first sharded observation lands.
         """
-        key = self._family_key(task.estimator, batched, n_shards)
+        key = self._family_key(task.estimator, batched, n_shards, n_classes)
         with self._lock:
             fam = self._buckets.get(key, {})
             stats = fam.get(param_bucket(_law_params(task)))
@@ -455,19 +468,24 @@ class CostModel:
             ratio = self._ratios.get(key)
             if ratio is not None and ratio.n and task.cost is not None and task.cost > 0:
                 return task.cost * ratio.factor()
-        got = self.predict(task, n_rows, batched=batched, n_shards=n_shards)
+        got = self.predict(task, n_rows, batched=batched, n_shards=n_shards,
+                           n_classes=n_classes)
         if got is None and n_shards > 1:
-            return self.estimate(task, n_rows, batched=batched)
+            return self.estimate(task, n_rows, batched=batched,
+                                 n_classes=n_classes)
         if got is None and batched:
-            return self.estimate(task, n_rows, batched=False)
+            return self.estimate(task, n_rows, batched=False,
+                                 n_classes=n_classes)
         return got
 
     def predict_many(self, tasks: Sequence[TrainTask], n_rows: int,
-                     *, n_shards: int = 1) -> dict[int, float]:
+                     *, n_shards: int = 1,
+                     n_classes: int = 0) -> dict[int, float]:
         """task_id -> estimate for every task the model can serve."""
         out: dict[int, float] = {}
         for t in tasks:
-            p = self.estimate(t, n_rows, n_shards=n_shards)
+            p = self.estimate(t, n_rows, n_shards=n_shards,
+                              n_classes=n_classes)
             if p is not None and p > 0:
                 out[t.task_id] = p
         return out
@@ -480,7 +498,8 @@ class CostModel:
         import time
 
         t0 = time.perf_counter()
-        costs = self.predict_many(tasks, getattr(data, "n_rows", 0))
+        costs = self.predict_many(tasks, getattr(data, "n_rows", 0),
+                                  n_classes=getattr(data, "n_classes", 0))
         unknown = [t for t in tasks if t.task_id not in costs]
         profiling_seconds = time.perf_counter() - t0
         sampling_rate = None

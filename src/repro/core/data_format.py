@@ -58,12 +58,17 @@ class DenseMatrix:
     """Row-oriented dense matrix with labels — the paper's uniform format.
 
     ``x``: (rows, features) float32, C-contiguous (row-major).
-    ``y``: (rows,) float32 labels (binary {0,1} for classification) or targets.
+    ``y``: (rows,) float32 labels (binary {0,1} for classification, class
+    ids for a multi-class task) or targets.
+    ``n_classes``: 0, or K for a K-class task whose labels are the class
+    ids 0..K−1 (:meth:`as_classes`); converters pass it on, and a family
+    with a softmax objective trains K outputs a row from it.
     """
 
     x: np.ndarray
     y: np.ndarray
     feature_names: tuple[str, ...] = ()
+    n_classes: int = 0
 
     def __post_init__(self):
         x = np.ascontiguousarray(np.asarray(self.x, dtype=np.float32))
@@ -74,15 +79,16 @@ class DenseMatrix:
             raise ValueError(
                 f"rows mismatch: x has {x.shape[0]}, y has {y.shape[0]}"
             )
+        k = int(self.n_classes)
+        if k and (k < 2 or np.any((y != np.round(y)) | (y < 0) | (y >= k))):
+            raise ValueError(f"a {k}-class DenseMatrix needs integer labels "
+                             f"in [0, {k}) and K >= 2")
         object.__setattr__(self, "x", x)
         object.__setattr__(self, "y", y)
+        object.__setattr__(self, "n_classes", k)
 
-    def fingerprint(self) -> str:
-        """Content hash: equal-content copies hash equal, any change in the
-        values, shapes or feature names changes it. Memoized per instance
-        (the arrays are frozen with the dataclass), so repeated cache lookups
-        cost a dict read, not a re-hash."""
-        cached = self.__dict__.get("_fingerprint")
+    def _content_fingerprint(self) -> str:
+        cached = self.__dict__.get("_content_fp")
         if cached is not None:
             return cached
         h = hashlib.blake2b(digest_size=16)
@@ -91,8 +97,45 @@ class DenseMatrix:
         h.update(self.x.tobytes())
         h.update(self.y.tobytes())
         fp = h.hexdigest()
+        object.__setattr__(self, "_content_fp", fp)
+        return fp
+
+    def fingerprint(self) -> str:
+        """Content hash: equal-content copies hash equal, any change in the
+        values, shapes, feature names or class count changes it. Memoized
+        per instance (the arrays are frozen with the dataclass), so repeated
+        cache lookups cost a dict read, not a re-hash."""
+        cached = self.__dict__.get("_fingerprint")
+        if cached is not None:
+            return cached
+        fp = self._content_fingerprint()
+        if self.n_classes:
+            fp = hashlib.blake2b(f"{fp}|classes={self.n_classes}".encode(),
+                                 digest_size=16).hexdigest()
         object.__setattr__(self, "_fingerprint", fp)
         return fp
+
+    def as_classes(self) -> "DenseMatrix":
+        """This data as a K-class task, K the number of distinct labels,
+        which must be the integer ids 0..K−1 (K >= 2). Checked once: the
+        view is memoized on this instance, and shares its content hash."""
+        cached = self.__dict__.get("_as_classes")
+        if cached is not None:
+            return cached
+        if self.n_classes:
+            return self
+        ids = np.unique(self.y)
+        k = int(ids.size)
+        if k < 2 or not np.array_equal(ids, np.arange(k, dtype=ids.dtype)):
+            shown = ids[:8].tolist()
+            raise ValueError(
+                "a multi-class task needs labels that are the class ids "
+                f"0..K-1, each present (K >= 2); got {k} distinct labels "
+                f"starting {shown}")
+        view = dataclasses.replace(self, n_classes=k)
+        object.__setattr__(view, "_content_fp", self._content_fingerprint())
+        object.__setattr__(self, "_as_classes", view)
+        return view
 
     @property
     def n_rows(self) -> int:
@@ -108,7 +151,8 @@ class DenseMatrix:
             raise ValueError(f"sample rate must be in (0, 1], got {rate}")
         n = max(1, int(round(self.n_rows * rate)))
         idx = np.random.default_rng(seed).choice(self.n_rows, size=n, replace=False)
-        return DenseMatrix(self.x[idx], self.y[idx], self.feature_names)
+        return DenseMatrix(self.x[idx], self.y[idx], self.feature_names,
+                           self.n_classes)
 
     def split(self, fractions: tuple[float, ...], seed: int = 0):
         """Split into len(fractions) DenseMatrix parts (e.g. 6:2:2)."""
@@ -120,7 +164,8 @@ class DenseMatrix:
                 self.n_rows * f / total
             )
             part = idx[start:stop]
-            out.append(DenseMatrix(self.x[part], self.y[part], self.feature_names))
+            out.append(DenseMatrix(self.x[part], self.y[part],
+                                   self.feature_names, self.n_classes))
             start = stop
         return tuple(out)
 
@@ -131,7 +176,8 @@ class DenseMatrix:
         if std is None:
             std = self.x.std(axis=0)
         std = np.where(std < 1e-12, 1.0, std)
-        return DenseMatrix((self.x - mean) / std, self.y, self.feature_names), mean, std
+        return (DenseMatrix((self.x - mean) / std, self.y, self.feature_names,
+                            self.n_classes), mean, std)
 
 
 # --------------------------------------------------------------------------
@@ -626,6 +672,7 @@ def _quantized_bins(data: DenseMatrix, max_bins: int = 256):
         "edges": jnp.asarray(edges.T),  # (n_feat, n_bins-1)
         "y": jnp.asarray(data.y),
         "n_bins": n_bins,
+        "n_classes": data.n_classes,
     }
 
 

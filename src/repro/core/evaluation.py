@@ -35,7 +35,8 @@ halves mirror the §3.2 fusion / §3.3 prepared-data architecture:
 
 :func:`stable_sigmoid` is the shared numerically-stable numpy sigmoid every
 family's ``predict_proba`` uses — the naive ``1/(1+exp(-z))`` overflows
-(RuntimeWarning, precision loss) for large negative margins.
+(RuntimeWarning, precision loss) for large negative margins;
+:func:`stable_softmax` is its K-class counterpart.
 """
 from __future__ import annotations
 
@@ -54,6 +55,7 @@ __all__ = [
     "evaluate_models",
     "predict_compile_cache",
     "stable_sigmoid",
+    "stable_softmax",
 ]
 
 
@@ -74,6 +76,19 @@ def stable_sigmoid(z: np.ndarray) -> np.ndarray:
     ez = np.exp(z[~pos])
     out[~pos] = ez / (1.0 + ez)
     return out
+
+
+def stable_softmax(z: np.ndarray) -> np.ndarray:
+    """Numerically stable softmax over the last axis (class margins to
+    class probabilities): shifts each row by its largest margin before
+    exponentiating, so no argument is positive and extreme margins neither
+    overflow nor turn into NaN. Computes in the input's floating dtype,
+    like :func:`stable_sigmoid`."""
+    z = np.asarray(z)
+    if z.dtype not in (np.float32, np.float64):
+        z = z.astype(np.float64)
+    e = np.exp(z - z.max(axis=-1, keepdims=True))
+    return e / e.sum(axis=-1, keepdims=True)
 
 
 #: process-wide cache of compiled PREDICT programs — deliberately separate
@@ -153,6 +168,9 @@ def evaluate_models(
                 probs = type(models[0]).predict_proba_batched(models, x, cache=cache)
             else:
                 probs = [models[0].predict_proba_jax(x, cache=cache)]
+            # outputs a row: 1 for P(y=1), K for a K-class model's (R, K)
+            sp.set(outputs=int(np.shape(probs[0])[1])
+                   if np.ndim(probs[0]) == 2 else 1)
             y = plan.data.y
             if sharded:
                 n_rows = int(entry["_n_rows"])
@@ -161,8 +179,9 @@ def evaluate_models(
                 y_blocks.reshape(-1)[:n_rows] = np.asarray(y).reshape(-1)
 
                 def score(p):
+                    p = np.asarray(p)
                     return sharded_metric(plan.metric, y_blocks,
-                                          np.asarray(p).reshape(valid.shape),
+                                          p.reshape(valid.shape + p.shape[1:]),
                                           valid, n_rows)
             else:
                 metric_fn = METRICS[plan.metric]

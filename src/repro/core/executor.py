@@ -113,7 +113,8 @@ def _run_fused_unit(unit: FusedBatch, data, eid: int,
             TaskResult(task=m, model=mod, train_seconds=per, executor_id=eid,
                        batch_size=len(members),
                        convert_seconds=conv if j == carrier else 0.0,
-                       score=scores[j], eval_seconds=eval_per)
+                       score=scores[j], eval_seconds=eval_per,
+                       trees=getattr(mod, "trees", 0))
             for j, (m, mod) in enumerate(zip(members, models))
         ]
     except ExecutorFailure:
@@ -175,7 +176,8 @@ def _run_solo(task, data, eid: int, validate: EvalPlan | None = None,
             score = scores[0]
     return TaskResult(task=task, model=model, train_seconds=secs,
                       executor_id=eid, convert_seconds=conv, score=score,
-                      eval_seconds=eval_s, resume_state=rstate)
+                      eval_seconds=eval_s, resume_state=rstate,
+                      trees=getattr(model, "trees", 0))
 
 
 class LocalExecutorPool:

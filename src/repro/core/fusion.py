@@ -388,13 +388,17 @@ def compile_cache() -> CompileCache:
 # Grouping.
 # --------------------------------------------------------------------------
 
-def _amortized(task: TrainTask, cost_model, n_rows: int) -> TrainTask:
+def _amortized(task: TrainTask, cost_model, n_rows: int,
+               n_classes: int = 0) -> TrainTask:
     """Re-cost a member with the CostModel's batched (amortized) law; the
     sequential estimate is the conservative fallback before any fused batch
     of the family has been observed."""
     if cost_model is None:
         return task
-    est = cost_model.estimate(task, n_rows, batched=True)
+    # a K-class task's law, where there is one (foreign cost models that
+    # know no classes are asked as before)
+    classes = {"n_classes": n_classes} if n_classes else {}
+    est = cost_model.estimate(task, n_rows, batched=True, **classes)
     return task.with_cost(est) if est is not None and est > 0 else task
 
 
@@ -404,6 +408,7 @@ def fuse_tasks(
     max_fuse: int = 16,
     cost_model=None,
     n_rows: int = 0,
+    n_classes: int = 0,
 ) -> list:
     """Pack tasks into fused units; unfusable tasks pass through unchanged.
 
@@ -417,6 +422,9 @@ def fuse_tasks(
     deterministic and re-fusing the same pending set yields the same units),
     and chunked into batches of at most ``max_fuse``. A chunk of one is
     returned as the bare task — fusing a singleton buys nothing.
+
+    ``n_classes`` is the data's class count (``DenseMatrix.n_classes``),
+    under which the CostModel keeps a K-class task's laws apart.
 
     Returns a mixed list of ``TrainTask`` and :class:`FusedBatch` that any
     ``scheduler.schedule*`` policy accepts directly.
@@ -455,7 +463,8 @@ def fuse_tasks(
             if len(chunk) == 1:
                 units.append((order[key], chunk[0][0]))
                 continue
-            fused = tuple(_amortized(t, cost_model, n_rows) for t, _ in chunk)
+            fused = tuple(_amortized(t, cost_model, n_rows, n_classes)
+                          for t, _ in chunk)
             units.append((order[key], FusedBatch(
                 tasks=fused, signature=key,
                 buckets=tuple(b for _, b in chunk),

@@ -205,6 +205,10 @@ class TaskResult:
     #: last abandoned attempt burned, which the CostModel observes as a
     #: censored runtime so the estimate that missed stops being trusted.
     timed_out: bool = False
+    #: trees the training call grew — GBDT: rounds trained × outputs a
+    #: row (a resumed rung counts its increment); 0 where the family does
+    #: not count them — set by the executor from ``TrainedModel.trees``
+    trees: int = 0
 
     @property
     def ok(self) -> bool:
@@ -214,12 +218,19 @@ class TaskResult:
 class TrainedModel(abc.ABC):
     """Prediction side of the common interface."""
 
+    #: trees the call that trained this model grew (see ``TaskResult.trees``)
+    trees: int = 0
+
     @abc.abstractmethod
     def predict_proba(self, x: np.ndarray) -> np.ndarray:
-        """Return P(y=1) scores, shape (rows,)."""
+        """Return P(y=1) scores, shape (rows,) — or, for a model of a
+        K-class task, the class probabilities, shape (rows, K)."""
 
     def predict(self, x: np.ndarray) -> np.ndarray:
-        return (self.predict_proba(x) >= 0.5).astype(np.float32)
+        proba = np.asarray(self.predict_proba(x))
+        if proba.ndim == 2:
+            return proba.argmax(axis=1).astype(np.float32)
+        return (proba >= 0.5).astype(np.float32)
 
     # ---- fused validation plane (DESIGN.md §3.4) ------------------------
     def predict_proba_jax(self, x, *, cache=None) -> np.ndarray:
@@ -267,6 +278,11 @@ class Estimator(abc.ABC):
     #: default ``eval_dense`` (features only; labels stay host-side for the
     #: numpy metric) serves all four.
     eval_format: str = "eval_dense"
+    #: the tasks (``core.results.TASKS``) this family has an objective for;
+    #: ``SearchSpec`` refuses a search whose metric scores another task.
+    #: ``"multiclass"`` trains K outputs a row on a ``DenseMatrix`` with
+    #: ``n_classes = K`` (softmax objective).
+    objectives: tuple[str, ...] = ("binary",)
     #: the hyperparameter that acts as the resumable-budget axis for adaptive
     #: search (gbdt ``"round"``, forest ``"n_estimators"``, logreg/mlp
     #: ``"steps"``). None = the family declares no budget axis; rung tasks

@@ -118,8 +118,13 @@ class AnalyticProfiler:
         self._cost_fn = cost_fn
 
     def profile(self, tasks: Sequence[TrainTask], data: DenseMatrix) -> ProfileReport:
+        """A K-class task's data (``n_classes = K``) passes K on to
+        ``estimate_cost``: only a family with a multi-class objective is
+        searched there, and its estimate scales its tree work by K."""
         t0 = time.perf_counter()
         costs: dict[int, float] = {}
+        k = getattr(data, "n_classes", 0)
+        classes = {"n_classes": k} if k else {}
         for t in tasks:
             if self._cost_fn is not None:
                 costs[t.task_id] = float(self._cost_fn(t, data.n_rows, data.n_features))
@@ -131,7 +136,8 @@ class AnalyticProfiler:
                         f"estimator {t.estimator!r} exposes no estimate_cost and "
                         "no cost_fn was given"
                     )
-                costs[t.task_id] = float(fn(dict(t.params), data.n_rows, data.n_features))
+                costs[t.task_id] = float(fn(dict(t.params), data.n_rows,
+                                            data.n_features, **classes))
         return ProfileReport(
             costs=costs,
             profiling_seconds=time.perf_counter() - t0,

@@ -20,7 +20,8 @@ import numpy as np
 from repro.core.data_format import DenseMatrix
 from repro.core.interface import TaskResult, TrainTask
 
-__all__ = ["MultiModel", "ModelScore", "auc", "accuracy", "logloss", "METRICS"]
+__all__ = ["MultiModel", "ModelScore", "Metric", "auc", "accuracy", "logloss",
+           "mlogloss", "METRICS", "TASKS", "metric_task"]
 
 
 def auc(y_true: np.ndarray, scores: np.ndarray) -> float:
@@ -58,11 +59,45 @@ def logloss(y_true: np.ndarray, scores: np.ndarray) -> float:
     return float(-(y * np.log(p) + (1 - y) * np.log(1 - p)).mean())
 
 
-METRICS: dict[str, Callable[[np.ndarray, np.ndarray], float]] = {
-    "auc": auc,
-    "accuracy": accuracy,
-    "neg_logloss": lambda y, s: -logloss(y, s),
+def mlogloss(y_true: np.ndarray, scores: np.ndarray) -> float:
+    """Multi-class log loss: the mean of −log p_y over rows, ``scores`` the
+    (rows, K) class probabilities and ``y_true`` class ids, p clipped to
+    [1e-7, 1] as :func:`logloss` clips."""
+    p = np.asarray(scores, dtype=np.float64)
+    y = np.asarray(y_true).astype(np.int64)
+    p_y = np.clip(p[np.arange(y.size), y], 1e-7, 1.0)
+    return float(-np.log(p_y).mean())
+
+
+#: the tasks a metric can state: ``binary`` scores P(y=1) of 0/1 labels,
+#: ``multiclass`` scores (rows, K) class probabilities of class ids 0..K−1
+TASKS = ("binary", "multiclass")
+
+
+@dataclasses.dataclass(frozen=True)
+class Metric:
+    """A validation metric and the task it scores. The task is what a
+    search trains for: ``SearchSpec.metric`` names a metric, and a
+    multi-class metric makes the search train softmax models."""
+
+    fn: Callable[[np.ndarray, np.ndarray], float]
+    task: str = "binary"
+
+    def __call__(self, y_true: np.ndarray, scores: np.ndarray) -> float:
+        return self.fn(y_true, scores)
+
+
+METRICS: dict[str, Metric] = {
+    "auc": Metric(auc),
+    "accuracy": Metric(accuracy),
+    "neg_logloss": Metric(lambda y, s: -logloss(y, s)),
+    "neg_mlogloss": Metric(lambda y, s: -mlogloss(y, s), task="multiclass"),
 }
+
+
+def metric_task(metric: str) -> str:
+    """The task the metric ``metric`` scores (:data:`TASKS`)."""
+    return METRICS[metric].task
 
 
 # --------------------------------------------------------------------------
@@ -87,23 +122,34 @@ def _logloss_partial(y, s, valid) -> float:
     return float(np.where(valid, terms, 0.0).sum())
 
 
+def _mlogloss_partial(y, s, valid) -> float:
+    p = np.asarray(s, dtype=np.float64)
+    yy = np.asarray(y).astype(np.int64)
+    p_y = np.clip(p[np.arange(yy.size), yy], 1e-7, 1.0)
+    return float(np.where(valid, -np.log(p_y), 0.0).sum())
+
+
 #: metric → (per-shard partial-sum fn, sign applied to the combined mean)
 METRIC_PARTIALS: dict[str, tuple[Callable, float]] = {
     "accuracy": (_accuracy_partial, 1.0),
     "neg_logloss": (_logloss_partial, -1.0),
+    "neg_mlogloss": (_mlogloss_partial, -1.0),
 }
 
 
 def sharded_metric(metric: str, y_blocks: np.ndarray, score_blocks: np.ndarray,
                    valid: np.ndarray, n_rows: int) -> float:
     """Score block-sharded predictions: ``y_blocks``/``score_blocks``/
-    ``valid`` are (S, Rs) with zero-padded tails. Decomposable metrics
+    ``valid`` are (S, Rs) with zero-padded tails (``score_blocks`` (S, Rs,
+    K) for a multi-class metric). Decomposable metrics
     combine per-shard (sum, count) partials; others gather in shard order
     (which IS row order) and run the global definition."""
     entry = METRIC_PARTIALS.get(metric)
     if entry is None:
         flat_y = np.asarray(y_blocks).reshape(-1)[:n_rows]
-        flat_s = np.asarray(score_blocks).reshape(-1)[:n_rows]
+        score_blocks = np.asarray(score_blocks)
+        flat_s = score_blocks.reshape(
+            (-1,) + score_blocks.shape[2:])[:n_rows]
         return float(METRICS[metric](flat_y, flat_s))
     partial_fn, sign = entry
     sums = sum(partial_fn(y_blocks[s], score_blocks[s], valid[s])

@@ -53,6 +53,8 @@ import itertools
 import time
 from typing import Callable, Iterator, Mapping
 
+import numpy as np
+
 from repro.core.backend import ExecutorBackend
 from repro.core.cost_model import CostModel, observed_drift
 from repro.core.data_format import DenseMatrix, prepared_data_cache
@@ -66,7 +68,7 @@ from repro.core.interface import (
     get_estimator,
     prepared_cache_key,
 )
-from repro.core.results import METRICS, MultiModel
+from repro.core.results import METRICS, MultiModel, metric_task
 from repro.core.scheduler import (
     charge_first_of_group,
     charge_units,
@@ -182,6 +184,10 @@ class Session:
         self._observer_installed = False
         #: process-unique id; every span of this search carries it
         self.search_id = next(_SEARCH_IDS)
+        #: K of a K-class search (set by results() from the training data
+        #: the metric's task marks), 0 for a binary one; keys the
+        #: CostModel's K-class laws apart
+        self._n_classes = 0
 
     # ------------------------------------------------------------------
     @property
@@ -260,10 +266,11 @@ class Session:
             prev = backend.on_result
             if getattr(prev, "_session_observer", False):
                 prev = prev._chained_prev      # drop the stale session's hook
-            n_shards = self.spec.n_shards
+            n_shards, n_classes = self.spec.n_shards, self._n_classes
 
             def _observe(res: TaskResult, _prev=prev) -> None:
-                cm.observe_result(res, n_rows, eval_rows, n_shards=n_shards)
+                cm.observe_result(res, n_rows, eval_rows, n_shards=n_shards,
+                                  n_classes=n_classes)
                 if _prev is not None:
                     _prev(res)
 
@@ -280,7 +287,8 @@ class Session:
         known: dict[int, float] = {}
         if cm is not None:
             known = cm.predict_many(batch, train.n_rows,
-                                    n_shards=self.spec.n_shards)
+                                    n_shards=self.spec.n_shards,
+                                    n_classes=self._n_classes)
             self.stats.n_model_estimates += len(known)
         unknown = [t for t in batch if t.task_id not in known]
         if unknown:
@@ -296,7 +304,8 @@ class Session:
         if cm is not None:
             out = []
             for t in pending:
-                p = cm.estimate(t, train.n_rows, n_shards=self.spec.n_shards)
+                p = cm.estimate(t, train.n_rows, n_shards=self.spec.n_shards,
+                                n_classes=self._n_classes)
                 out.append(t.with_cost(p) if p is not None and p > 0 else t)
             return out
         # no model (foreign setup): per-family observed/estimated correction
@@ -374,7 +383,7 @@ class Session:
         if cm is None or eval_plan is None:
             return list(units)
         n_eval = eval_plan.data.n_rows
-        n_shards = self.spec.n_shards
+        n_shards, n_classes = self.spec.n_shards, self._n_classes
         member_vals: dict[int, dict[int, float | None]] = {}
 
         def extra(u):
@@ -383,11 +392,13 @@ class Session:
                 # reused by apply — a split piece keeps exactly its own
                 # members' eval share
                 vals = {m.task_id: cm.predict_eval(m, n_eval,
-                                                   n_shards=n_shards)
+                                                   n_shards=n_shards,
+                                                   n_classes=n_classes)
                         for m in u.tasks}
                 member_vals[u.task_id] = vals
                 return sum(v for v in vals.values() if v) or None
-            return cm.predict_eval(u, n_eval, n_shards=n_shards)
+            return cm.predict_eval(u, n_eval, n_shards=n_shards,
+                                   n_classes=n_classes)
 
         def apply(u, e):
             if isinstance(u, FusedBatch):
@@ -400,7 +411,8 @@ class Session:
     def _fuse(self, costed, cm: CostModel | None, n_rows: int):
         """Pack a costed batch into fused units (spec.fuse) and account them."""
         units = fuse_tasks(costed, max_fuse=self.spec.max_fuse,
-                           cost_model=cm, n_rows=n_rows)
+                           cost_model=cm, n_rows=n_rows,
+                           n_classes=self._n_classes)
         fused = [u for u in units if isinstance(u, FusedBatch)]
         self.stats.n_fused_batches += len(fused)
         self.stats.n_fused_tasks += sum(u.batch_size for u in fused)
@@ -417,7 +429,8 @@ class Session:
         def recost(m):
             if cm is not None:
                 est = cm.estimate(m, n_rows, batched=True,
-                                  n_shards=self.spec.n_shards)
+                                  n_shards=self.spec.n_shards,
+                                  n_classes=self._n_classes)
                 if est is not None and est > 0:
                     return m.with_cost(est)
             return by_id.get(m.task_id, m)
@@ -440,6 +453,24 @@ class Session:
                 units.append(by_id[u.task_id])
         return units
 
+    def _task_data(self, train: DenseMatrix,
+                   validate: DenseMatrix | None) -> DenseMatrix:
+        """The training data as the metric's task reads it: a multi-class
+        metric makes it a K-class task (``DenseMatrix.as_classes``, labels
+        checked once per dataset), so every family trains its softmax
+        objective; the validation labels must be class ids below K."""
+        if metric_task(self.spec.metric) != "multiclass":
+            return train
+        train = train.as_classes()
+        k = train.n_classes
+        if validate is not None:
+            y = validate.y
+            if np.any((y != np.round(y)) | (y < 0) | (y >= k)):
+                raise ValueError(f"validation labels must be class ids in "
+                                 f"[0, {k}), the training data's classes")
+        self._n_classes = k
+        return train
+
     # ------------------------------------------------------------------
     def results(
         self,
@@ -459,6 +490,7 @@ class Session:
                                "(or Session.resume the WAL) to search again")
         spec = self.spec
         t_start = time.perf_counter()
+        train = self._task_data(train, validate)
         tuner = spec.build_tuner()
         profiler = spec.build_profiler()
         cm = self._ensure_cost_model(profiler)
@@ -601,7 +633,8 @@ class Session:
                         cm.observe_result(
                             res, train.n_rows,
                             validate.n_rows if validate is not None else 0,
-                            n_shards=spec.n_shards)
+                            n_shards=spec.n_shards,
+                            n_classes=self._n_classes)
                     if tuner.is_dynamic:
                         # feed the tuner the moment the result lands — this
                         # is what lets ASHA promote (and kill) mid-round

@@ -29,8 +29,9 @@ import dataclasses
 from typing import Any, Mapping
 
 from repro.core.grid import GridBuilder, SearchSpace
+from repro.core.interface import get_estimator
 from repro.core.profiler import AnalyticProfiler, SamplingProfiler
-from repro.core.results import METRICS
+from repro.core.results import METRICS, metric_task
 from repro.core.tuner import TUNER_KINDS, GridSearchTuner, Tuner, make_tuner
 
 __all__ = ["SearchSpec", "POLICIES"]
@@ -209,6 +210,31 @@ class SearchSpec:
         object.__setattr__(self, "n_shards", int(self.n_shards))
         if self.n_shards < 1:
             raise ValueError(f"n_shards must be >= 1, got {self.n_shards}")
+        self._check_task(spaces)
+
+    def _check_task(self, spaces) -> None:
+        """The metric's task needs an objective in every family searched
+        (``Estimator.objectives``); a multi-class search runs on replicated
+        data only."""
+        task = metric_task(self.metric)
+        if task == "binary":
+            return
+        if self.n_shards > 1:
+            raise ValueError(f"metric {self.metric!r} scores a {task} task, "
+                             "which has no row-sharded placement; use "
+                             "n_shards=1")
+        for sp in spaces:
+            try:
+                objectives = get_estimator(sp.estimator).objectives
+            except KeyError:
+                raise ValueError(
+                    f"family {sp.estimator!r} is not registered, so its "
+                    f"objectives for metric {self.metric!r} are unknown"
+                ) from None
+            if task not in objectives:
+                raise ValueError(
+                    f"family {sp.estimator!r} has no {task} objective, so "
+                    f"it cannot be searched by metric {self.metric!r}")
 
     # -- construction helpers ------------------------------------------
     @classmethod

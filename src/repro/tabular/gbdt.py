@@ -14,6 +14,12 @@ gets a sentinel split (bin B−1 → every row routes left), so row→leaf routi
 stays a fixed-shape gather chain and the whole training loop is one
 ``lax.scan`` over rounds under jit. Hyperparameters follow XGBoost naming
 (eta, round, max_depth, max_bin, lambda, gamma, min_child_weight).
+
+Two objectives: the logistic loss (one tree a round, scalar margins), and
+for a K-class task (prepared data with ``n_classes = K``) XGBoost's
+``multi:softprob`` — softmax margins, K trees a round grown by
+``build_tree`` mapped over a class axis, so each level of all K trees is
+one ``ops.level_split`` launch (DESIGN.md §3.10).
 """
 from __future__ import annotations
 
@@ -26,7 +32,11 @@ import numpy as np
 
 from repro.core import tracing
 from repro.core.data_format import is_sharded_payload
-from repro.core.evaluation import predict_compile_cache, stable_sigmoid
+from repro.core.evaluation import (
+    predict_compile_cache,
+    stable_sigmoid,
+    stable_softmax,
+)
 from repro.core.interface import (
     Estimator,
     ResumeState,
@@ -154,22 +164,35 @@ def predict_raw_margin(x, feat, thresh, leaves, base, *, max_depth: int):
     carry ``thresh = +inf`` (``x > inf`` is False → every row routes left),
     so depth-padded and round-padded trees route exactly like the numpy
     predictor; a fully-sentinel PADDING tree lands every row in leaf 0,
-    whose value is 0, adding nothing to the margin."""
+    whose value is 0, adding nothing to the margin.
+
+    A K-class stack has (rounds, K, ·) trees and a (K,) base: each round
+    routes its K trees at once (mapped over the class axis) and the
+    margins come back (R, K)."""
     r = x.shape[0]
 
-    def one_tree(margin, tree):
-        tf, tt, tl = tree
+    def route(tf, tt, tl):
         local = jnp.zeros((r,), jnp.int32)
         for level in range(max_depth):
             g = (1 << level) - 1 + local
             xv = jnp.take_along_axis(x, tf[g][:, None], axis=1)[:, 0]
             local = 2 * local + (xv > tt[g]).astype(jnp.int32)
-        return margin + tl[local], 0.0
+        return tl[local]
+
+    classes = feat.ndim == 3
+    if classes:
+        route = jax.vmap(route)
+
+    def one_tree(margin, tree):
+        return margin + route(*tree), 0.0
 
     with jax.named_scope("tree_routing"):
-        margin0 = jnp.full((r,), jnp.float32(0.0), jnp.float32) + base
+        if classes:
+            margin0 = jnp.zeros((feat.shape[1], r), jnp.float32) + base[:, None]
+        else:
+            margin0 = jnp.full((r,), jnp.float32(0.0), jnp.float32) + base
         margin, _ = jax.lax.scan(one_tree, margin0, (feat, thresh, leaves))
-    return margin
+    return margin.T if classes else margin
 
 
 def _build_predict_batched(max_depth: int):
@@ -188,11 +211,11 @@ def _stack_tree_models(models) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     from repro.core.fusion import pad_pow2
 
     pad_t = pad_pow2(max(m.feat.shape[0] for m in models))
-    b, n_nodes = len(models), models[0].feat.shape[1]
-    n_leaves = models[0].leaves.shape[1]
-    feat = np.zeros((b, pad_t, n_nodes), np.int32)
-    thresh = np.full((b, pad_t, n_nodes), np.inf, np.float32)
-    leaves = np.zeros((b, pad_t, n_leaves), np.float32)
+    b, nodes = len(models), models[0].feat.shape[1:]
+    leaf_shape = models[0].leaves.shape[1:]
+    feat = np.zeros((b, pad_t) + nodes, np.int32)
+    thresh = np.full((b, pad_t) + nodes, np.inf, np.float32)
+    leaves = np.zeros((b, pad_t) + leaf_shape, np.float32)
     for i, m in enumerate(models):
         t = m.feat.shape[0]
         feat[i, :t] = m.feat
@@ -204,36 +227,50 @@ def _stack_tree_models(models) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
 def batched_tree_margins(models, x, *, cache=None) -> np.ndarray:
     """(B, rows) margins for a stack of heap-layout tree models (GBDT with
     its base margin, forest with base 0) — shared by both families' jitted
-    paths. Models are grouped by depth (a fused unit is a single group by
-    construction; mixed stacks still score correctly), each group one
-    vmapped program through the predict compile cache."""
+    paths; (B, rows, K) for K-class GBDT models. Models are grouped by
+    depth (a fused unit is a single group by construction; mixed stacks
+    still score correctly), each group one vmapped program through the
+    predict compile cache."""
     cache = cache if cache is not None else predict_compile_cache()
     x = jnp.asarray(x, jnp.float32)
-    out = np.empty((len(models), x.shape[0]), np.float32)
+    classes = models[0].feat.shape[1:-1]          # () or (K,)
+    out = np.empty((len(models), x.shape[0]) + classes, np.float32)
     groups: dict[int, list[int]] = {}
     for i, m in enumerate(models):
         groups.setdefault(int(m.max_depth), []).append(i)
     for depth, idxs in groups.items():
         feat, thresh, leaves = _stack_tree_models([models[i] for i in idxs])
         fn = cache.get(
-            ("tree_predict", depth, feat.shape[1], len(idxs), tuple(x.shape)),
+            ("tree_predict", depth, feat.shape[1], len(idxs), tuple(x.shape))
+            + classes,
             lambda: _build_predict_batched(depth),
         )
-        base = jnp.asarray([getattr(models[i], "base", 0.0) for i in idxs],
-                           jnp.float32)
+        base = jnp.asarray(np.stack(
+            [np.broadcast_to(np.float32(getattr(models[i], "base", 0.0)),
+                             classes) for i in idxs]), jnp.float32)
         margins = fn(x, jnp.asarray(feat), jnp.asarray(thresh),
                      jnp.asarray(leaves), base)
         out[idxs] = np.asarray(margins)
     return out
 
 
-def note_level_rows(data, n_bins: int) -> None:
+def note_level_rows(data, n_bins: int, **meta) -> None:
     """Name on the open ``repro.train`` span which rows the fit's levels
     below the root read: ``ops.level_rows`` of its width, or ``"all"`` for
-    row-sharded data, whose levels mask rows in place (DESIGN.md §3.9)."""
+    row-sharded data, whose levels mask rows in place (DESIGN.md §3.9).
+    ``meta`` adds what else the fit knows (GBDT: ``outputs``, ``trees``)."""
     rows = ("all" if is_sharded_payload(data)
             else ops.level_rows(int(data["bins"].shape[-1]), n_bins))
-    tracing.annotate(level_rows=rows)
+    tracing.annotate(level_rows=rows, **meta)
+
+
+def n_classes_of(data) -> int:
+    """K of prepared data for a K-class task (``DenseMatrix.n_classes``),
+    0 for a binary one. Row-sharded placements have no multi-class fit."""
+    k = int(data.get("n_classes", 0) or 0)
+    if k and is_sharded_payload(data):
+        raise ValueError("multi-class GBDT has no row-sharded placement")
+    return k
 
 
 def _coarse_bins(bins, factor):
@@ -250,10 +287,66 @@ def _coarse_bins(bins, factor):
     return q + ((q + 1) * factor <= bins).astype(bins.dtype)
 
 
+def _boosting_round(cbins, y, n_rounds, depth_limit, bin_limit, eta, lam,
+                    gamma, min_child_weight, *, n_classes: int, n_bins: int,
+                    max_depth: int, subtract: bool, force, axis_name,
+                    row_valid):
+    """The round both cores scan: ``margin -> (margin', tree)``.
+
+    ``n_classes = 0``: the logistic loss, one tree a round on (R,) margins.
+    ``n_classes = K``: softmax over (K, R) margins (XGBoost's
+    ``multi:softprob``: g_k = p_k − [y = k], h_k = 2·p_k·(1 − p_k)), and
+    ``build_tree`` and the margin routing mapped over the class axis, so a
+    level of all K trees is one ``ops.level_split`` launch and the tree
+    arrays gain a K axis after the round axis."""
+    grow = functools.partial(
+        build_tree, cbins, n_bins=n_bins, max_depth=max_depth,
+        lam=lam, gamma=gamma, min_child_weight=min_child_weight,
+        depth_limit=depth_limit, bin_limit=bin_limit,
+        subtract=subtract, force=force,
+        axis_name=axis_name, row_valid=row_valid)
+    route = functools.partial(predict_margin, cbins, max_depth=max_depth)
+    if n_classes:
+        onehot = (y[None, :] == jnp.arange(n_classes, dtype=y.dtype)[:, None]
+                  ).astype(jnp.float32)
+        grow, route = jax.vmap(grow), jax.vmap(route)
+
+    def one_round(margin, r_idx):
+        with jax.named_scope("gradients"):
+            if n_classes:
+                p = jax.nn.softmax(margin, axis=0)
+                g = p - onehot
+                h = jnp.maximum(2.0 * p * (1.0 - p), 1e-16)
+            else:
+                p = jax.nn.sigmoid(margin)
+                g = p - y
+                h = jnp.maximum(p * (1.0 - p), 1e-16)
+        feat, split, leaf_g, leaf_h = grow(g, h)
+        with jax.named_scope("margin_update"):
+            # where (not multiply): an empty padded leaf is 0/(0+λ), which for
+            # λ=0 is NaN and would poison the margin through a plain mask
+            leaf_value = jnp.where(
+                r_idx < n_rounds, -eta * leaf_g / (leaf_h + lam), 0.0)
+            margin = margin + route(feat, split, leaf_value)
+        return margin, (feat, split, leaf_value)
+
+    return one_round
+
+
+def _initial_margin(base, n_rows: int, n_classes: int):
+    """Every row's starting margin: (R,) of the scalar base, or (K, R) of
+    the (K,) base margins."""
+    if n_classes:
+        return jnp.broadcast_to(
+            jnp.asarray(base, jnp.float32)[:, None], (n_classes, n_rows))
+    return jnp.full((n_rows,), base, jnp.float32)
+
+
 def _fit_gbdt_core(
     bins, y, base, factor, bin_limit, n_rounds, depth_limit,
     eta, lam, gamma, min_child_weight, *, n_bins: int, rounds: int, max_depth: int,
     subtract: bool = True, force=None, axis_name=None, row_valid=None,
+    n_classes: int = 0,
 ):
     """One GBDT fit over PADDED maxima (rounds/max_depth/n_bins static).
 
@@ -261,40 +354,26 @@ def _fit_gbdt_core(
     per-config structural LIMITS (factor, bin_limit, n_rounds, depth_limit)
     are traced — so one compile serves every config sharing the maxima, and
     ``jax.vmap`` over the traced args turns a whole config stack into one
-    fused program (``train_batched``). Masking keeps padded work inert:
+    fused program (``train_batched``; the class axis of a K-class fit then
+    sits inside the config axis). Masking keeps padded work inert:
     rounds past ``n_rounds`` add zero-valued trees, levels past
     ``depth_limit`` force sentinel splits, bins past ``bin_limit`` never win.
     """
     r = bins.shape[0]
     cbins = _coarse_bins(bins, factor)
-
-    def one_round(margin, r_idx):
-        with jax.named_scope("gradients"):
-            p = jax.nn.sigmoid(margin)
-            g = p - y
-            h = jnp.maximum(p * (1.0 - p), 1e-16)
-        feat, split, leaf_g, leaf_h = build_tree(
-            cbins, g, h, n_bins=n_bins, max_depth=max_depth,
-            lam=lam, gamma=gamma, min_child_weight=min_child_weight,
-            depth_limit=depth_limit, bin_limit=bin_limit,
-            subtract=subtract, force=force,
-            axis_name=axis_name, row_valid=row_valid,
-        )
-        with jax.named_scope("margin_update"):
-            # where (not multiply): an empty padded leaf is 0/(0+λ), which for
-            # λ=0 is NaN and would poison the margin through a plain mask
-            leaf_value = jnp.where(
-                r_idx < n_rounds, -eta * leaf_g / (leaf_h + lam), 0.0)
-            margin = margin + predict_margin(cbins, feat, split, leaf_value, max_depth)
-        return margin, (feat, split, leaf_value)
-
-    margin0 = jnp.full((r,), base, jnp.float32)
+    one_round = _boosting_round(
+        cbins, y, n_rounds, depth_limit, bin_limit, eta, lam, gamma,
+        min_child_weight, n_classes=n_classes, n_bins=n_bins,
+        max_depth=max_depth, subtract=subtract, force=force,
+        axis_name=axis_name, row_valid=row_valid)
+    margin0 = _initial_margin(base, r, n_classes)
     _, trees = jax.lax.scan(one_round, margin0, jnp.arange(rounds))
-    return trees  # (rounds, 2^D−1) ×2, (rounds, 2^D)
+    return trees  # (rounds, [K,] 2^D−1) ×2, (rounds, [K,] 2^D)
 
 
 _fit_gbdt = functools.partial(
-    jax.jit, static_argnames=("n_bins", "rounds", "max_depth", "subtract", "force")
+    jax.jit, static_argnames=("n_bins", "rounds", "max_depth", "subtract",
+                              "force", "n_classes")
 )(_fit_gbdt_core)
 
 
@@ -303,6 +382,7 @@ def _resume_gbdt_core(
     eta, lam, gamma, min_child_weight, start,
     *, n_bins: int, rounds: int, max_depth: int,
     subtract: bool = True, force=None, axis_name=None, row_valid=None,
+    n_classes: int = 0,
 ):
     """Boost ``rounds`` MORE trees on top of a carried margin — the rung
     machinery (DESIGN.md §3.6). Round indices continue from ``start`` and the
@@ -312,31 +392,18 @@ def _resume_gbdt_core(
     the UNPADDED increment — no masked tail whose ``+0.0`` margin adds could
     flip -0.0 bits between the chained and the straight run."""
     cbins = _coarse_bins(bins, factor)
-
-    def one_round(margin, r_idx):
-        with jax.named_scope("gradients"):
-            p = jax.nn.sigmoid(margin)
-            g = p - y
-            h = jnp.maximum(p * (1.0 - p), 1e-16)
-        feat, split, leaf_g, leaf_h = build_tree(
-            cbins, g, h, n_bins=n_bins, max_depth=max_depth,
-            lam=lam, gamma=gamma, min_child_weight=min_child_weight,
-            depth_limit=depth_limit, bin_limit=bin_limit,
-            subtract=subtract, force=force,
-            axis_name=axis_name, row_valid=row_valid,
-        )
-        with jax.named_scope("margin_update"):
-            leaf_value = jnp.where(
-                r_idx < n_rounds, -eta * leaf_g / (leaf_h + lam), 0.0)
-            margin = margin + predict_margin(cbins, feat, split, leaf_value, max_depth)
-        return margin, (feat, split, leaf_value)
-
+    one_round = _boosting_round(
+        cbins, y, n_rounds, depth_limit, bin_limit, eta, lam, gamma,
+        min_child_weight, n_classes=n_classes, n_bins=n_bins,
+        max_depth=max_depth, subtract=subtract, force=force,
+        axis_name=axis_name, row_valid=row_valid)
     margin, trees = jax.lax.scan(one_round, margin0, start + jnp.arange(rounds))
     return trees, margin
 
 
 _resume_gbdt = functools.partial(
-    jax.jit, static_argnames=("n_bins", "rounds", "max_depth", "subtract", "force")
+    jax.jit, static_argnames=("n_bins", "rounds", "max_depth", "subtract",
+                              "force", "n_classes")
 )(_resume_gbdt_core)
 
 
@@ -423,29 +490,55 @@ def _build_batched_sharded_fit(n_bins: int, rounds: int, max_depth: int,
 
 
 def _build_batched_fit(n_bins: int, rounds: int, max_depth: int,
-                       subtract: bool = True, force=None):
+                       subtract: bool = True, force=None, n_classes: int = 0):
     """Compile-cache builder: vmap the core over the per-config args (data,
     labels and base margin are shared across the batch)."""
     core = functools.partial(
         _fit_gbdt_core, n_bins=n_bins, rounds=rounds, max_depth=max_depth,
-        subtract=subtract, force=force)
+        subtract=subtract, force=force, n_classes=n_classes)
     return jax.jit(jax.vmap(core, in_axes=(None, None, None) + (0,) * 8))
 
 
 class GBDTModel(TrainedModel):
-    """Raw-feature predictor: thresholds are bin edges mapped back to floats."""
+    """Raw-feature predictor: thresholds are bin edges mapped back to floats.
 
-    def __init__(self, feat, thresh, leaves, base: float, max_depth: int):
-        self.feat = np.asarray(feat)       # (rounds, 2^D − 1) int32
-        self.thresh = np.asarray(thresh)   # (rounds, 2^D − 1) f32 (+inf = left)
-        self.leaves = np.asarray(leaves)   # (rounds, 2^D) f32
-        self.base = float(base)
+    Binary: trees (rounds, ·) and a scalar ``base``; ``predict_proba`` is
+    P(y=1). K classes: trees (rounds, K, ·) — round r's tree for class k at
+    ``[r, k]`` — and ``base`` (K,); ``predict_proba`` is the (rows, K)
+    softmax of the class margins."""
+
+    def __init__(self, feat, thresh, leaves, base, max_depth: int,
+                 trees: int | None = None):
+        self.feat = np.asarray(feat)       # (rounds, [K,] 2^D − 1) int32
+        self.thresh = np.asarray(thresh)   # (rounds, [K,] 2^D − 1) f32 (+inf = left)
+        self.leaves = np.asarray(leaves)   # (rounds, [K,] 2^D) f32
+        self.base = (np.asarray(base, np.float32) if self.feat.ndim == 3
+                     else float(base))
         self.max_depth = max_depth
+        #: trees grown by the call that made this model (a resumed rung
+        #: grows only its increment); every tree it holds by default
+        self.trees = (int(np.prod(self.feat.shape[:-1])) if trees is None
+                      else int(trees))
+
+    @property
+    def n_classes(self) -> int:
+        """K of a K-class model, 0 for a binary one."""
+        return int(self.feat.shape[1]) if self.feat.ndim == 3 else 0
 
     def predict_margin(self, x: np.ndarray) -> np.ndarray:
+        """(rows,) margins, or (rows, K) for a K-class model: each class's
+        trees summed in round order onto its base margin."""
         x = np.asarray(x, np.float32)
-        out = np.full((x.shape[0],), self.base, np.float32)
-        for feat, thresh, leaves in zip(self.feat, self.thresh, self.leaves):
+        if self.n_classes:
+            return np.stack([
+                self._margin(x, self.feat[:, k], self.thresh[:, k],
+                             self.leaves[:, k], self.base[k])
+                for k in range(self.n_classes)], axis=1)
+        return self._margin(x, self.feat, self.thresh, self.leaves, self.base)
+
+    def _margin(self, x, feats, threshs, leaves_stack, base) -> np.ndarray:
+        out = np.full((x.shape[0],), base, np.float32)
+        for feat, thresh, leaves in zip(feats, threshs, leaves_stack):
             local = np.zeros(x.shape[0], np.int64)
             for level in range(self.max_depth):
                 g = (1 << level) - 1 + local
@@ -453,8 +546,12 @@ class GBDTModel(TrainedModel):
             out += leaves[local]
         return out
 
+    def _proba(self, margins: np.ndarray) -> np.ndarray:
+        return (stable_softmax(margins) if self.n_classes
+                else stable_sigmoid(margins))
+
     def predict_proba(self, x: np.ndarray) -> np.ndarray:
-        return stable_sigmoid(self.predict_margin(x))
+        return self._proba(self.predict_margin(x))
 
     # ---- jitted validation plane (DESIGN.md §3.4) -----------------------
     def predict_margin_jax(self, x, *, cache=None) -> np.ndarray:
@@ -464,9 +561,9 @@ class GBDTModel(TrainedModel):
         return batched_tree_margins([self], x, cache=cache)[0]
 
     def predict_proba_jax(self, x, *, cache=None) -> np.ndarray:
-        # same stable sigmoid as predict_proba over bit-identical margins,
+        # same stable link as predict_proba over bit-identical margins,
         # so the jitted path scores EXACTLY what the numpy path would
-        return stable_sigmoid(self.predict_margin_jax(x, cache=cache))
+        return self._proba(self.predict_margin_jax(x, cache=cache))
 
     @classmethod
     def predict_margin_batched(cls, models, x, *, cache=None) -> np.ndarray:
@@ -474,7 +571,8 @@ class GBDTModel(TrainedModel):
 
     @classmethod
     def predict_proba_batched(cls, models, x, *, cache=None) -> np.ndarray:
-        return stable_sigmoid(batched_tree_margins(models, x, cache=cache))
+        margins = batched_tree_margins(models, x, cache=cache)
+        return models[0]._proba(margins)
 
 
 @register_estimator
@@ -482,6 +580,7 @@ class GBDTEstimator(Estimator):
     name = "gbdt"
     data_format = "quantized_bins"
     budget_param = "round"
+    objectives = ("binary", "multiclass")
 
     def default_params(self) -> dict[str, Any]:
         return {
@@ -510,7 +609,15 @@ class GBDTEstimator(Estimator):
         return factor, -(-n_bins // factor)
 
     @staticmethod
-    def _base_margin(y) -> float:
+    def _base_margin(y, n_classes: int = 0):
+        """The logit of the label mean, or for K classes the (K,) log class
+        priors (float32), each prior floored at 1e-6 (a row sample may miss
+        a class)."""
+        if n_classes:
+            counts = np.bincount(np.asarray(y).astype(np.int64),
+                                 minlength=n_classes)[:n_classes]
+            prior = np.clip(counts / max(1, counts.sum()), 1e-6, 1.0)
+            return np.log(prior).astype(np.float32)
         prior = float(np.clip(np.asarray(y).mean(), 1e-6, 1 - 1e-6))
         return float(np.log(prior / (1 - prior)))
 
@@ -539,7 +646,9 @@ class GBDTEstimator(Estimator):
         bins, edges, y = data["bins"], data["edges"], data["y"]
         factor, n_cbins = self._coarsen(int(data["n_bins"]), int(p["max_bin"]))
         max_depth, rounds = int(p["max_depth"]), int(p["round"])
-        note_level_rows(data, n_cbins)
+        k = n_classes_of(data)
+        note_level_rows(data, n_cbins, outputs=max(1, k),
+                        trees=rounds * max(1, k))
         if is_sharded_payload(data):
             base = self._sharded_base_margin(data)
             feat, split, leaves = _fit_gbdt_sharded(
@@ -552,14 +661,14 @@ class GBDTEstimator(Estimator):
                 n_shards=int(data["_n_shards"]),
             )
         else:
-            base = self._base_margin(y)
+            base = self._base_margin(y, k)
             feat, split, leaves = _fit_gbdt(
-                bins, y, jnp.float32(base),
+                bins, y, jnp.asarray(base, jnp.float32) if k else jnp.float32(base),
                 jnp.int32(factor), jnp.int32(n_cbins),
                 jnp.int32(rounds), jnp.int32(max_depth),
                 jnp.float32(p["eta"]), jnp.float32(p["lambda"]),
                 jnp.float32(p["gamma"]), jnp.float32(p["min_child_weight"]),
-                n_bins=n_cbins, rounds=rounds, max_depth=max_depth,
+                n_bins=n_cbins, rounds=rounds, max_depth=max_depth, n_classes=k,
             )
         feat_np, split_np = np.asarray(feat), np.asarray(split)
         thresh = self._thresholds(feat_np, split_np, np.asarray(edges), factor, n_cbins)
@@ -572,24 +681,29 @@ class GBDTEstimator(Estimator):
         bins, edges, y = data["bins"], data["edges"], data["y"]
         factor, n_cbins = self._coarsen(int(data["n_bins"]), int(p["max_bin"]))
         max_depth = int(p["max_depth"])
-        note_level_rows(data, n_cbins)
         sharded = is_sharded_payload(data)
-        base = self._sharded_base_margin(data) if sharded else self._base_margin(y)
+        k = n_classes_of(data)
+        base = (self._sharded_base_margin(data) if sharded
+                else self._base_margin(y, k))
         target = int(budget)
         if state is None:
             start = 0
             # sharded margins carry per-shard blocks: same (S, Rs) layout
             # as the labels, so rung-resume keeps rows on their home shard
-            margin0 = jnp.full(np.shape(y), base, jnp.float32)
+            margin0 = (_initial_margin(base, int(np.shape(y)[0]), k) if k
+                       else jnp.full(np.shape(y), base, jnp.float32))
+            classes = (k,) if k else ()
             n_nodes, n_leaves = (1 << max_depth) - 1, 1 << max_depth
-            prev_feat = np.zeros((0, n_nodes), np.int32)
-            prev_thresh = np.zeros((0, n_nodes), np.float32)
-            prev_leaves = np.zeros((0, n_leaves), np.float32)
+            prev_feat = np.zeros((0,) + classes + (n_nodes,), np.int32)
+            prev_thresh = np.zeros((0,) + classes + (n_nodes,), np.float32)
+            prev_leaves = np.zeros((0,) + classes + (n_leaves,), np.float32)
         else:
             start = int(state.budget)
             pl = state.payload
             margin0 = jnp.asarray(pl["margin"], jnp.float32)
             prev_feat, prev_thresh, prev_leaves = pl["feat"], pl["thresh"], pl["leaves"]
+        grown = max(0, target - start) * max(1, k)
+        note_level_rows(data, n_cbins, outputs=max(1, k), trees=grown)
         if target > start:
             common = (
                 jnp.int32(factor), jnp.int32(n_cbins),
@@ -608,6 +722,7 @@ class GBDTEstimator(Estimator):
                 (feat, split, leaves), margin = _resume_gbdt(
                     bins, y, margin0, *common,
                     n_bins=n_cbins, rounds=target - start, max_depth=max_depth,
+                    n_classes=k,
                 )
             feat_np, split_np = np.asarray(feat), np.asarray(split)
             thresh = self._thresholds(feat_np, split_np, np.asarray(edges),
@@ -616,7 +731,8 @@ class GBDTEstimator(Estimator):
             prev_thresh = np.concatenate([prev_thresh, thresh])
             prev_leaves = np.concatenate([prev_leaves, np.asarray(leaves)])
             margin0 = margin
-        model = GBDTModel(prev_feat, prev_thresh, prev_leaves, base, max_depth)
+        model = GBDTModel(prev_feat, prev_thresh, prev_leaves, base, max_depth,
+                          trees=grown)
         new_state = ResumeState(self.name, max(target, start),
                                 {"feat": prev_feat, "thresh": prev_thresh,
                                  "leaves": prev_leaves,
@@ -652,7 +768,10 @@ class GBDTEstimator(Estimator):
         pad_bins = max(nc for _, nc in coarse)
         pad_rounds = fusion.pad_pow2(max(int(p["round"]) for p in ps))
         pad_depth = max(int(p["max_depth"]) for p in ps)
-        note_level_rows(data, pad_bins)
+        k = n_classes_of(data)
+        note_level_rows(data, pad_bins, outputs=max(1, k),
+                        trees=sum(int(p["round"]) for p in ps[:n_real])
+                        * max(1, k))
         cc = cache if cache is not None else fusion.compile_cache()
         if is_sharded_payload(data):
             n_shards = int(data["_n_shards"])
@@ -665,12 +784,15 @@ class GBDTEstimator(Estimator):
             )
             shared = (bins, y, data["_shard_valid"], jnp.float32(base))
         else:
-            base = self._base_margin(y)
+            base = self._base_margin(y, k)
             fit = cc.get(
-                ("gbdt", pad_bins, pad_rounds, pad_depth, len(ps), tuple(bins.shape)),
-                lambda: _build_batched_fit(pad_bins, pad_rounds, pad_depth),
+                ("gbdt", pad_bins, pad_rounds, pad_depth, len(ps),
+                 tuple(bins.shape), k),
+                lambda: _build_batched_fit(pad_bins, pad_rounds, pad_depth,
+                                           n_classes=k),
             )
-            shared = (bins, y, jnp.float32(base))
+            shared = (bins, y,
+                      jnp.asarray(base, jnp.float32) if k else jnp.float32(base))
         col = lambda vals, dt: jnp.asarray(np.asarray(vals, dtype=dt))  # noqa: E731
         feat, split, leaves = fit(
             *shared,
@@ -697,14 +819,17 @@ class GBDTEstimator(Estimator):
         return models
 
     @staticmethod
-    def estimate_cost(params: Mapping[str, Any], n_rows: int, n_features: int) -> float:
+    def estimate_cost(params: Mapping[str, Any], n_rows: int, n_features: int,
+                      n_classes: int = 0) -> float:
         """Analytic-profiler hook: histogram work dominates — R·F adds at
         the root, then histogram subtraction (DESIGN.md §3.8) builds only
         the smaller child per level, so every level below the root costs
-        ~half: effective histogram levels = 1 + (D−1)/2 (plus split scans)."""
+        ~half: effective histogram levels = 1 + (D−1)/2 (plus split scans).
+        A K-class fit grows K trees a round, K times the work."""
         p = {"round": 30, "max_depth": 6, "max_bin": 64, **dict(params)}
         depth = int(p["max_depth"])
         hist_levels = 1 + 0.5 * (depth - 1)
         per_tree = n_rows * n_features * hist_levels
         split_scan = (1 << depth) * n_features * int(p["max_bin"])
-        return int(p["round"]) * (per_tree + split_scan) / 2e8
+        return (int(p["round"]) * max(1, n_classes) * (per_tree + split_scan)
+                / 2e8)

@@ -141,3 +141,28 @@ def test_level_kernel_names_its_own_device_op(one_chip):
     calls = re.findall(r"^\s*%?([\w.\-]+) = .* custom-call\(", text, re.M)
     assert calls and all(re.match(r"fused_level_split_tpu(\.\d+)?$", c)
                          for c in calls), calls
+
+
+def test_class_axis_level_compiles_for_v5e(one_chip):
+    """One level of all 7 trees of a Covertype round, as softmax GBDT runs
+    it: the class axis mapped over ``ops.level_split``'s masked path (every
+    one of 348,607 rows where it lies, 54 features at 128 bins), the bin
+    matrix shared — one kernel launch for the seven trees."""
+    r, f, b, n_nodes, k = 348_607, 54, 128, 8, 7
+    assert ops.level_rows(f, b, force="kernel") == "all"
+
+    def level(bins, g, h, node, parent, _sil, fmask, lam):
+        sil, _, snode = ops._plan_smaller_child(node, n_nodes, compact=False)
+        return fused_level_split_tpu(
+            bins, g, h, snode, n_nodes=n_nodes, n_bins=b, lam=lam,
+            min_child_weight=1.0, feat_mask=fmask, parent_hist=parent,
+            small_is_left=sil, return_hist=True)
+
+    classes = jax.vmap(level, in_axes=(None, 0, 0, 0, 0, 0, None, None))
+    shapes = list(_level_shapes(one_chip, r, f, b, n_nodes, True, batch=k))
+    shapes[6] = jax.ShapeDtypeStruct((f,), jnp.bool_, sharding=one_chip)
+    shapes[7] = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    text = jax.jit(classes).lower(*shapes).compile().as_text()
+    # one kernel launch, its outputs carrying the seven trees
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(r"fused_level_split_tpu[.\d]* = \(f32\[7,", text)
